@@ -459,6 +459,7 @@ from jax.sharding import Mesh
 
 from repro import api as miso
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 
 SIZES = %(sizes)r
 STEPS = %(steps)d
@@ -476,7 +477,7 @@ def timeit(fn, *args):
 
 def mesh_for(level):
     if level == 2:
-        return jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        return make_mesh((2, 2, 2), ("pod", "data", "model"))
     return Mesh(np.array(jax.devices()[:6]).reshape(3, 2, 1),
                 ("pod", "data", "model"))
 
@@ -595,6 +596,7 @@ import jax
 
 from repro import api as miso
 from repro.configs import get_reduced
+from repro.launch.mesh import make_mesh
 from repro.models.lm_cells import ServeConfig
 from repro.serving import Request
 from repro.serving.lm import lm_engine_parts
@@ -610,7 +612,7 @@ cfg = dc.replace(cfg, d_model=32, n_layers=2, d_ff=64, n_heads=2,
                  n_kv_heads=1, vocab_size=128)
 
 def drive(placement):
-    mesh = (jax.make_mesh((PODS, 8 // PODS), ("pod", "data"))
+    mesh = (make_mesh((PODS, 8 // PODS), ("pod", "data"))
             if placement == "spatial" else None)
     scfg = ServeConfig(batch=SLOTS, max_len=32, placement=placement)
     prog, adapter = lm_engine_parts(cfg, scfg)

@@ -83,6 +83,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import api as miso
+from repro.launch.mesh import make_mesh
 
 # ---------------------------------------------------------------------------
 # 1. A MISO program: a 1-D heat rod (SIMD stencil cell) + a probe cell (MIMD)
@@ -191,7 +192,7 @@ print(f"TMR        : corrected in-graph={bool(ok)} "
 #     inherited Executor protocol.
 # ---------------------------------------------------------------------------
 if PLACEMENT == "spatial":
-    mesh = jax.make_mesh((2,), ("pod",))
+    mesh = make_mesh((2,), ("pod",))
     sp = miso.compile(prog, backend="auto", mesh=mesh,
                       policies={"rod": miso.RedundancyPolicy(
                           level=2, placement="spatial", compare="hash")})

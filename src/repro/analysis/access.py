@@ -25,7 +25,7 @@ import dataclasses
 from typing import Any, Callable, Mapping
 
 import jax
-from jax import core as jcore
+from jax.extend import core as jcore
 from jax.tree_util import keystr, tree_flatten_with_path
 
 Pytree = Any

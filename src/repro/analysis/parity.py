@@ -29,7 +29,7 @@ from __future__ import annotations
 
 from typing import Callable
 
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from .access import CellAccess, _subjaxpr
 from .diagnostics import Diagnostic

@@ -49,7 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.distributed.collectives import (
     bcast_pytree,
     exchange_pytree,

@@ -56,12 +56,14 @@ from __future__ import annotations
 import collections
 import dataclasses
 import time
+import warnings
 from typing import Any, Callable, Iterator, Mapping, Optional
 
 import jax
 import jax.numpy as jnp
 
 from .fault import FaultSpec
+from .jit import forwarding_jit
 from .program import MisoProgram
 from .redundancy import (
     FaultLedger,
@@ -534,7 +536,9 @@ class LockstepExecutor(Executor):
         #: (states', reports).  Exposed for lowering/cost analysis (the
         #: dry-run driver) and for embedding in larger jit programs.
         self.step_fn = step_fn
-        self._jit_step = jax.jit(step_fn)
+        # pass-through cells (static weights) come back as the same
+        # buffers instead of a fresh copy per step
+        self._jit_step = forwarding_jit(step_fn)
         self._jit_plain_window = None   # lazy: pure_step(compare=False)
         self._run_cache: dict = {}
 
@@ -568,7 +572,7 @@ class LockstepExecutor(Executor):
                         states, reports = plain(states, step_idx + j, fault)
                     return states, reports
 
-                self._jit_plain_window = jax.jit(window)
+                self._jit_plain_window = forwarding_jit(window)
             with self._mesh_ctx():
                 return self._jit_plain_window(
                     states, jnp.int32(int(step_idx)), fault)
@@ -687,6 +691,8 @@ class LockstepExecutor(Executor):
         if n_steps % k != 0:
             raise ValueError("n_steps must be a multiple of compare_every")
         start = self._t if start_step is None else int(start_step)
+        # one batched spec strikes any cell: the stack's static part agrees
+        flist = [dataclasses.replace(f, cells=None) for f in flist]
         stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *flist)
         iters = n_steps // k
         # compiled-campaign cache, sibling of the run() scan cache: states
@@ -1100,6 +1106,15 @@ def compile(
             # back-end honors it (replicas on distinct pods), so it wins
             # over the graph-shape choice
             backend = "spatial_lockstep"
+        else:
+            spatial = sorted(n for n, c in program.cells.items()
+                             if c.redundancy.level > 1
+                             and c.redundancy.placement == "spatial")
+            if spatial:
+                warnings.warn(
+                    f"cells {spatial} ask for spatial placement, but the "
+                    f"mesh cannot hold them; their replicas run temporally "
+                    f"on {backend!r}", stacklevel=2)
     try:
         cls = BACKENDS[backend]
     except KeyError:

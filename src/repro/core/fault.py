@@ -4,12 +4,15 @@ Transitions are pure, so two replica executions are bit-identical unless the
 hardware misbehaves.  To *test* the dependability machinery we emulate a
 particle strike: flip one bit of one replica's freshly-computed state.  The
 fault is described by a ``FaultSpec`` of plain int32 scalars and threaded
-through the (jitted) step function, so arming/disarming a fault never
-recompiles.
+through the (jitted) step function, so re-arming a fault never recompiles.
+The cells a spec can strike are static when known on the host: a disarmed
+step traces no injection at all, so cells it leaves unchanged stay the
+very same buffers (``core/jit.py``).
 """
 from __future__ import annotations
 
 import dataclasses
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -41,18 +44,28 @@ class FaultSpec:
     leaf: jax.Array      # int32: which state leaf (flatten order)
     index: jax.Array     # int32: flat element index within the leaf
     bit: jax.Array       # int32: bit position (mod leaf bit-width)
+    #: static: ids of the cells this spec can strike, or None for any cell
+    #: (a spec whose ``cell_id`` is only known traced, or a stacked batch)
+    cells: Optional[tuple[int, ...]] = dataclasses.field(
+        default=None, metadata=dict(static=True))
+
+    def may_strike(self, cell_id: int) -> bool:
+        return self.cells is None or cell_id in self.cells
 
     @staticmethod
     def none() -> "FaultSpec":
         z = jnp.int32(-1)
-        return FaultSpec(step=z, cell_id=z, replica=z, leaf=z, index=z, bit=z)
+        return FaultSpec(step=z, cell_id=z, replica=z, leaf=z, index=z, bit=z,
+                         cells=())
 
     @staticmethod
     def at(step, cell_id, replica=0, leaf=0, index=0, bit=0) -> "FaultSpec":
         i32 = lambda v: jnp.asarray(v, jnp.int32)
+        known = isinstance(cell_id, (int, np.integer))
         return FaultSpec(
             step=i32(step), cell_id=i32(cell_id), replica=i32(replica),
             leaf=i32(leaf), index=i32(index), bit=i32(bit),
+            cells=(int(cell_id),) if known else None,
         )
 
 
@@ -71,6 +84,8 @@ def inject(
     version forced a full all-gather of every state leaf per step, which
     dominated the roofline collective term — see EXPERIMENTS.md §Perf).
     """
+    if not spec.may_strike(cell_id):
+        return replicated_state
     leaves, treedef = jax.tree.flatten(replicated_state)
     hit_cell = (spec.cell_id == jnp.int32(cell_id)) & (spec.step == step)
 

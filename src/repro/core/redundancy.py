@@ -240,7 +240,7 @@ def run_transition(
             raise undeclared_read_error(
                 cell, e.args[0] if e.args else e, tuple(canon)
             ) from None
-        if fault is not None:
+        if fault is not None and fault.may_strike(cell_id):
             # unprotected cells are still physically strikeable — the flip
             # simply goes undetected (the paper's motivating failure mode)
             exp = jax.tree.map(lambda x: x[None], new)

@@ -16,54 +16,54 @@ per-step dependability epilogue into ONE ``pallas_call`` per cell:
     word counts (the permanent-fault localization signal), and the voted
     stream's fingerprint, in a single pass (3 reads + 1 write per word).
 
-Both kernels emit per-grid-block partials that the wrappers combine
-exactly (wraparound uint32 sums / xors and integer sums), so results are
-independent of the block size and bit-identical to the separate
-``tmr_vote``/``state_hash`` kernels they fuse.  On CPU CI they run with
-``interpret=True``; on TPU they are the fast path.
+Both kernels accumulate lane-wise partials that the wrappers fold exactly
+(wraparound uint32 sums / xors and integer sums; ``state_hash.stream_call``),
+so results are independent of the block size and bit-identical to the
+separate ``tmr_vote``/``state_hash`` kernels they fuse.  On CPU CI they run
+with ``interpret=True``; on TPU they are the fast path.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 
-from jax.experimental import pallas as pl
-
-from repro.compat import pallas_tpu_compiler_params
-
-# the fingerprint accumulator math lives in ONE place (state_hash.py) so
-# the bit-for-bit equality the parity gates rely on cannot drift
-from .state_hash import block_fingerprint, combine_partials, global_indices
+# the fingerprint math and the word-stream driver live in ONE place
+# (state_hash.py) so the bit-for-bit equality the parity gates rely on
+# cannot drift
+from .state_hash import (
+    FINGERPRINT_OPS,
+    LANES,
+    SUBLANES,
+    SUM,
+    fingerprint_terms,
+    stream_call,
+)
 
 #: VMEM-friendly default: 64Ki words = 256 KiB per replica stream.
 DEFAULT_BLOCK = 64 * 1024
+#: one (8, 128) uint32 tile: the granule a multi-block stream is cut into
+TILE = SUBLANES * LANES
 
 
 def pick_block(total_words: int, cap: int = DEFAULT_BLOCK) -> int:
     """Words per grid step for a state of ``total_words`` u32 words: one
-    lane-aligned block for small states, the VMEM cap for large ones (the
+    tile-aligned block for small states, the VMEM cap for large ones (the
     flat stream is zero-padded to a multiple of the block)."""
     if total_words >= cap:
         return cap
-    return max(128, -(-total_words // 128) * 128)
+    return max(TILE, -(-total_words // TILE) * TILE)
+
+
+_FP_ACC = [(op, jnp.uint32) for op in FINGERPRINT_OPS]
 
 
 # --------------------------------------------------------------------------
 # DMR: compare + both fingerprints, one pass
 # --------------------------------------------------------------------------
-def _dmr_kernel(a_ref, b_ref, diff_ref, hash_ref, *, block: int):
-    a = a_ref[...].reshape(1, block)
-    b = b_ref[...].reshape(1, block)
-    diff_ref[0, 0] = jnp.sum((a != b).astype(jnp.int32))
-    i = global_indices(block)
-    for r, v in enumerate((a, b)):
-        h1, h2, h3, h4 = block_fingerprint(v, i)
-        hash_ref[0, r, 0] = h1
-        hash_ref[0, r, 1] = h2
-        hash_ref[0, r, 2] = h3
-        hash_ref[0, r, 3] = h4
+def _dmr_slab(words, idx):
+    a, b = words
+    return None, [(a != b).astype(jnp.int32),
+                  *fingerprint_terms(a, idx), *fingerprint_terms(b, idx)]
 
 
 def dmr_compare(
@@ -74,49 +74,20 @@ def dmr_compare(
     flat uint32 replica streams of equal length, in one fused pass."""
     assert a.ndim == 1 and a.shape == b.shape
     assert a.dtype == jnp.uint32 and b.dtype == jnp.uint32
-    n = a.shape[0]
-    block = min(block, n)
-    assert n % block == 0, (n, block)
-    g = n // block
-    diff, hashes = pl.pallas_call(
-        functools.partial(_dmr_kernel, block=block),
-        grid=(g,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))] * 2,
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, 2, 4), lambda i: (i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((g, 1), jnp.int32),
-            jax.ShapeDtypeStruct((g, 2, 4), jnp.uint32),
-        ],
-        compiler_params=pallas_tpu_compiler_params(
-            dimension_semantics=("arbitrary",),
-        ),
-        interpret=interpret,
-    )(a.reshape(g, block), b.reshape(g, block))
-    return jnp.sum(diff, axis=(0, 1)), combine_partials(hashes)
+    _, t = stream_call(_dmr_slab, [a, b], block=block,
+                       acc=[(SUM, jnp.int32)] + _FP_ACC * 2,
+                       interpret=interpret)
+    return t[0], jnp.stack(t[1:]).reshape(2, 4)
 
 
 # --------------------------------------------------------------------------
 # TMR: vote + counts + voted fingerprint, one pass
 # --------------------------------------------------------------------------
-def _tmr_kernel(a_ref, b_ref, c_ref, voted_ref, counts_ref, hash_ref,
-                *, block: int):
-    a = a_ref[...].reshape(1, block)
-    b = b_ref[...].reshape(1, block)
-    c = c_ref[...].reshape(1, block)
+def _tmr_slab(words, idx):
+    a, b, c = words
     v = (a & b) | (a & c) | (b & c)
-    voted_ref[...] = v.reshape(voted_ref.shape)
-    counts_ref[0, 0] = jnp.sum((a != v).astype(jnp.int32))
-    counts_ref[0, 1] = jnp.sum((b != v).astype(jnp.int32))
-    counts_ref[0, 2] = jnp.sum((c != v).astype(jnp.int32))
-    counts_ref[0, 3] = jnp.int32(0)
-    h1, h2, h3, h4 = block_fingerprint(v, global_indices(block))
-    hash_ref[0, 0] = h1
-    hash_ref[0, 1] = h2
-    hash_ref[0, 2] = h3
-    hash_ref[0, 3] = h4
+    return v, [*((r != v).astype(jnp.int32) for r in (a, b, c)),
+               *fingerprint_terms(v, idx)]
 
 
 def tmr_step(
@@ -127,28 +98,7 @@ def tmr_step(
     fingerprint[4]) over three flat uint32 replica streams, one pass."""
     assert a.ndim == 1 and a.shape == b.shape == c.shape
     assert a.dtype == jnp.uint32
-    n = a.shape[0]
-    block = min(block, n)
-    assert n % block == 0, (n, block)
-    g = n // block
-    voted, counts, hashes = pl.pallas_call(
-        functools.partial(_tmr_kernel, block=block),
-        grid=(g,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))] * 3,
-        out_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, 4), lambda i: (i, 0)),
-            pl.BlockSpec((1, 4), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((g, block), jnp.uint32),
-            jax.ShapeDtypeStruct((g, 4), jnp.int32),
-            jax.ShapeDtypeStruct((g, 4), jnp.uint32),
-        ],
-        compiler_params=pallas_tpu_compiler_params(
-            dimension_semantics=("arbitrary",),
-        ),
-        interpret=interpret,
-    )(a.reshape(g, block), b.reshape(g, block), c.reshape(g, block))
-    return (voted.reshape(n), jnp.sum(counts, axis=0)[:3],
-            combine_partials(hashes))
+    voted, t = stream_call(_tmr_slab, [a, b, c], block=block,
+                           acc=[(SUM, jnp.int32)] * 3 + _FP_ACC,
+                           with_out=True, interpret=interpret)
+    return voted, jnp.stack(t[:3]), jnp.stack(t[3:])

@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -49,14 +47,13 @@ def _resolve_interpret(interpret: bool | None) -> bool:
 # --------------------------------------------------------------------------
 def _gqa_kernel(
     pm_ref,  # (B, P) int32 scalar-prefetch: page table (-1 = unmapped)
-    pos_ref,  # (B,) int32 scalar-prefetch: current query position
     q_ref,  # (1, Hq, Dk) block
     k_ref,  # (1, Hkv, ps, Dk) block: the page selected by the index map
     v_ref,  # (1, Hkv, ps, Dk) block
+    valid_ref,  # (1, 1, S) int32 block: lane is mapped and <= pos
     o_ref,  # (1, Hq, Dk) block
     k_scr,  # (Hkv, S, Dk) VMEM scratch, S = P * ps
     v_scr,  # (Hkv, S, Dk) VMEM scratch
-    m_scr,  # (1, S) int32 VMEM scratch: per-lane mapped flag
     *,
     scale: float,
     ps: int,
@@ -67,14 +64,13 @@ def _gqa_kernel(
     b = pl.program_id(0)
     i = pl.program_id(1)
     ok = pm_ref[b, i] >= 0
+    at = pl.multiple_of(i * ps, ps)
     # unmapped pages gather as zeros — exactly the dense empty-cache bytes
-    k_scr[:, pl.ds(i * ps, ps), :] = jnp.where(ok, k_ref[0], 0)
-    v_scr[:, pl.ds(i * ps, ps), :] = jnp.where(ok, v_ref[0], 0)
-    m_scr[:, pl.ds(i * ps, ps)] = jnp.broadcast_to(ok.astype(jnp.int32), (1, ps))
+    k_scr[:, pl.ds(at, ps), :] = jnp.where(ok, k_ref[0], 0)
+    v_scr[:, pl.ds(at, ps), :] = jnp.where(ok, v_ref[0], 0)
 
     @pl.when(i == n_pages_per_slot - 1)
     def _finalize():
-        seq = n_pages_per_slot * ps
         q = q_ref[0]  # (Hq, Dk)
         dk = q.shape[-1]
         qf = q.reshape(hkv, group, dk).astype(jnp.float32) * scale
@@ -83,15 +79,29 @@ def _gqa_kernel(
         s = jax.lax.dot_general(
             qf, kf, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
         )  # (Hkv, G, S)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, seq), 1)
-        valid = (m_scr[...] > 0) & (lane <= pos_ref[b])
-        s = jnp.where(valid[None], s, NEG_INF)
+        s = jnp.where(valid_ref[0][None] > 0, s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
         vf = v_scr[...].astype(jnp.float32)
         o = jax.lax.dot_general(
             p, vf, (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
         )  # (Hkv, G, Dk)
         o_ref[0] = o.reshape(hkv * group, dk).astype(o_ref.dtype)
+
+
+def _valid_lanes(pages: jax.Array, pos: jax.Array, ps: int) -> jax.Array:
+    """(B, 1, S) int32: lane s is on a mapped page and at or before the
+    query position.  Built in XLA, so the kernel reads it as one lane-dense
+    block instead of storing per-page flags at unaligned lane offsets."""
+    mapped = jnp.repeat(pages >= 0, ps, axis=1)  # (B, S)
+    lane = jnp.arange(mapped.shape[1], dtype=jnp.int32)
+    valid = mapped & (lane[None] <= pos.astype(jnp.int32)[:, None])
+    return valid.astype(jnp.int32)[:, None]
+
+
+def _vmem_limit(scratch_bytes: int) -> int:
+    """Scoped-VMEM budget: the gathered scratch plus the f32 copies the
+    finalize makes of it, with headroom, within the v5e's 128 MiB."""
+    return min(3 * scratch_bytes + (16 << 20), 120 << 20)
 
 
 def paged_gqa_attention(
@@ -120,36 +130,36 @@ def paged_gqa_attention(
     kernel = functools.partial(
         _gqa_kernel, scale=scale, ps=ps, n_pages_per_slot=P, hkv=Hkv, group=G
     )
+    page = pl.BlockSpec(
+        (1, Hkv, ps, Dk), lambda b, i, pm: (jnp.maximum(pm[b, i], 0), 0, 0, 0)
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=1,
         grid=(B, P),
         in_specs=[
-            pl.BlockSpec((1, Hq, Dk), lambda b, i, pm, ps_: (b, 0, 0)),
-            pl.BlockSpec(
-                (1, Hkv, ps, Dk),
-                lambda b, i, pm, ps_: (jnp.maximum(pm[b, i], 0), 0, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, Hkv, ps, Dk),
-                lambda b, i, pm, ps_: (jnp.maximum(pm[b, i], 0), 0, 0, 0),
-            ),
+            pl.BlockSpec((1, Hq, Dk), lambda b, i, pm: (b, 0, 0)),
+            page,
+            page,
+            pl.BlockSpec((1, 1, seq), lambda b, i, pm: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, Hq, Dk), lambda b, i, pm, ps_: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hq, Dk), lambda b, i, pm: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((Hkv, seq, Dk), k_pool.dtype),
             pltpu.VMEM((Hkv, seq, Dk), v_pool.dtype),
-            pltpu.VMEM((1, seq), jnp.int32),
         ],
     )
+    scratch = 2 * Hkv * seq * Dk * k_pool.dtype.itemsize
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, Dk), q.dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(scratch),
         ),
         interpret=_resolve_interpret(interpret),
-    )(pages.astype(jnp.int32), pos.astype(jnp.int32), q, k_pool, v_pool)
+        name="paged_gqa_attention",
+    )(pages.astype(jnp.int32), q, k_pool, v_pool, _valid_lanes(pages, pos, ps))
 
 
 # --------------------------------------------------------------------------
@@ -157,15 +167,14 @@ def paged_gqa_attention(
 # --------------------------------------------------------------------------
 def _mla_kernel(
     pm_ref,  # (B, P) int32
-    pos_ref,  # (B,) int32
     ql_ref,  # (1, h, lora) block: latent-absorbed query
     qr_ref,  # (1, h, rope) block: rope query
     ckv_ref,  # (1, ps, lora) block: selected latent page
     kr_ref,  # (1, ps, rope) block: selected rope page
+    valid_ref,  # (1, 1, S) int32 block: lane is mapped and <= pos
     o_ref,  # (1, h, lora) f32 block: latent context
     ckv_scr,  # (S, lora) VMEM scratch
     kr_scr,  # (S, rope) VMEM scratch
-    m_scr,  # (1, S) int32 VMEM scratch
     *,
     scale: float,
     ps: int,
@@ -174,13 +183,12 @@ def _mla_kernel(
     b = pl.program_id(0)
     i = pl.program_id(1)
     ok = pm_ref[b, i] >= 0
-    ckv_scr[pl.ds(i * ps, ps), :] = jnp.where(ok, ckv_ref[0], 0)
-    kr_scr[pl.ds(i * ps, ps), :] = jnp.where(ok, kr_ref[0], 0)
-    m_scr[:, pl.ds(i * ps, ps)] = jnp.broadcast_to(ok.astype(jnp.int32), (1, ps))
+    at = pl.multiple_of(i * ps, ps)
+    ckv_scr[pl.ds(at, ps), :] = jnp.where(ok, ckv_ref[0], 0)
+    kr_scr[pl.ds(at, ps), :] = jnp.where(ok, kr_ref[0], 0)
 
     @pl.when(i == n_pages_per_slot - 1)
     def _finalize():
-        seq = n_pages_per_slot * ps
         qlf = ql_ref[0].astype(jnp.float32)  # (h, lora)
         qrf = qr_ref[0].astype(jnp.float32)  # (h, rope)
         ckv = ckv_scr[...].astype(jnp.float32)  # (S, lora)
@@ -193,9 +201,7 @@ def _mla_kernel(
             qrf, kr, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
         s = (s_lat + s_rope) * scale
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, seq), 1)
-        valid = (m_scr[...] > 0) & (lane <= pos_ref[b])
-        s = jnp.where(valid, s, NEG_INF)
+        s = jnp.where(valid_ref[0] > 0, s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
         o_ref[0] = jax.lax.dot_general(
             p, ckv, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -224,35 +230,41 @@ def paged_mla_attention(
 
     kernel = functools.partial(_mla_kernel, scale=scale, ps=ps, n_pages_per_slot=P)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=1,
         grid=(B, P),
         in_specs=[
-            pl.BlockSpec((1, h, lora), lambda b, i, pm, ps_: (b, 0, 0)),
-            pl.BlockSpec((1, h, rope), lambda b, i, pm, ps_: (b, 0, 0)),
+            pl.BlockSpec((1, h, lora), lambda b, i, pm: (b, 0, 0)),
+            pl.BlockSpec((1, h, rope), lambda b, i, pm: (b, 0, 0)),
             pl.BlockSpec(
-                (1, ps, lora),
-                lambda b, i, pm, ps_: (jnp.maximum(pm[b, i], 0), 0, 0),
+                (1, ps, lora), lambda b, i, pm: (jnp.maximum(pm[b, i], 0), 0, 0)
             ),
             pl.BlockSpec(
-                (1, ps, rope),
-                lambda b, i, pm, ps_: (jnp.maximum(pm[b, i], 0), 0, 0),
+                (1, ps, rope), lambda b, i, pm: (jnp.maximum(pm[b, i], 0), 0, 0)
             ),
+            pl.BlockSpec((1, 1, seq), lambda b, i, pm: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, h, lora), lambda b, i, pm, ps_: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, lora), lambda b, i, pm: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((seq, lora), ckv_pool.dtype),
             pltpu.VMEM((seq, rope), krope_pool.dtype),
-            pltpu.VMEM((1, seq), jnp.int32),
         ],
     )
-    pm = pages.astype(jnp.int32)
-    qpos = pos.astype(jnp.int32)
+    scratch = seq * (lora + rope) * ckv_pool.dtype.itemsize
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, h, lora), jnp.float32),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(scratch),
         ),
         interpret=_resolve_interpret(interpret),
-    )(pm, qpos, q_lat, q_rope, ckv_pool, krope_pool)
+        name="paged_mla_attention",
+    )(
+        pages.astype(jnp.int32),
+        q_lat,
+        q_rope,
+        ckv_pool,
+        krope_pool,
+        _valid_lanes(pages, pos, ps),
+    )

@@ -9,6 +9,11 @@ living in scratch rather than shared memory.
 
 Cumulative sums inside the chunk are computed with a lower-triangular ones
 matmul (MXU-friendly and deterministic) instead of a serial scan.
+
+The kernel works head-major: the wrapper lays x/B/C out as (B, heads, L, .)
+so each block's last two dims are (chunk, width) — the TPU's (8, 128)
+tiling rule holds for any chunk that is a multiple of 8 — and the per-head
+decay ``a`` is read from SMEM.
 """
 from __future__ import annotations
 
@@ -17,7 +22,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.compat import pallas_tpu_compiler_params
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -32,13 +36,11 @@ def _ssd_kernel(
     def _init():
         s_ref[...] = h0_ref[...].reshape(s_ref.shape).astype(jnp.float32)
 
-    p_dim = x_ref.shape[-1]
-    n_dim = b_ref.shape[-1]
-    x = x_ref[...].reshape(chunk, p_dim).astype(jnp.float32)   # (Q, P)
-    dt = dt_ref[...].reshape(chunk, 1).astype(jnp.float32)     # (Q, 1)
-    a = a_ref[0, 0].astype(jnp.float32)                        # scalar
-    bm = b_ref[...].reshape(chunk, n_dim).astype(jnp.float32)  # (Q, N)
-    cm = c_ref[...].reshape(chunk, n_dim).astype(jnp.float32)  # (Q, N)
+    x = x_ref[0, 0].astype(jnp.float32)                        # (Q, P)
+    dt = dt_ref[0, 0].astype(jnp.float32)                      # (Q, 1)
+    a = a_ref[pl.program_id(1)]                                # scalar f32
+    bm = b_ref[0, 0].astype(jnp.float32)                       # (Q, N)
+    cm = c_ref[0, 0].astype(jnp.float32)                       # (Q, N)
 
     da = dt * a                                        # (Q, 1)
     # inclusive cumsum via lower-triangular ones matmul
@@ -61,9 +63,9 @@ def _ssd_kernel(
     )
 
     # state update: S = exp(cum_last) S_prev + sum_j exp(cum_last-cum_j) dt_j B_j x_j^T
-    cum_last = cum[chunk - 1]                                   # (1,)
-    wlast = jnp.exp(cum_last[None, :] - cum) * dt               # (Q, 1)
-    s_new = jnp.exp(cum_last)[:, None] * s_prev + jax.lax.dot_general(
+    cum_last = jnp.sum(da)                                      # scalar
+    wlast = jnp.exp(cum_last - cum) * dt                        # (Q, 1)
+    s_new = jnp.exp(cum_last) * s_prev + jax.lax.dot_general(
         bm * wlast, x, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                                           # (N, P)
@@ -95,38 +97,40 @@ def ssd_scan(
     n_chunks = L // chunk
     if h0 is None:
         h0 = jnp.zeros((B, H, N, P), jnp.float32)
-    a2 = a.reshape(H, 1)
-    dt3 = dt[..., None]  # (B, L, H, 1) so blocks keep a 2D+ trailing layout
+    # head-major layouts: (B, H|G, L, width)
+    xt = x.transpose(0, 2, 1, 3)
+    dtt = dt.transpose(0, 2, 1)[..., None]
+    bt = b.transpose(0, 2, 1, 3)
+    ct = c.transpose(0, 2, 1, 3)
 
     kernel = functools.partial(_ssd_kernel, chunk=chunk, n_chunks=n_chunks)
     grid = (B, H, n_chunks)
+    head = lambda w: pl.BlockSpec(  # noqa: E731
+        (1, 1, chunk, w), lambda bi, h, ci: (bi, h, ci, 0))
+    group = pl.BlockSpec(
+        (1, 1, chunk, N), lambda bi, h, ci, rep=rep: (bi, h // rep, ci, 0))
+    state = pl.BlockSpec((1, 1, N, P), lambda bi, h, ci: (bi, h, 0, 0))
     y, ht = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, P), lambda bi, h, ci: (bi, ci, h, 0)),
-            pl.BlockSpec((1, chunk, 1, 1), lambda bi, h, ci: (bi, ci, h, 0)),
-            pl.BlockSpec((1, 1), lambda bi, h, ci: (h, 0)),
-            pl.BlockSpec(
-                (1, chunk, 1, N), lambda bi, h, ci, rep=rep: (bi, ci, h // rep, 0)
-            ),
-            pl.BlockSpec(
-                (1, chunk, 1, N), lambda bi, h, ci, rep=rep: (bi, ci, h // rep, 0)
-            ),
-            pl.BlockSpec((1, 1, N, P), lambda bi, h, ci: (bi, h, 0, 0)),
+            head(P),
+            head(1),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            group,
+            group,
+            state,
         ],
-        out_specs=[
-            pl.BlockSpec((1, chunk, 1, P), lambda bi, h, ci: (bi, ci, h, 0)),
-            pl.BlockSpec((1, 1, N, P), lambda bi, h, ci: (bi, h, 0, 0)),
-        ],
+        out_specs=[head(P), state],
         out_shape=[
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
+            jax.ShapeDtypeStruct(xt.shape, x.dtype),
             jax.ShapeDtypeStruct((B, H, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(x, dt3, a2, b, c, h0)
-    return y, ht
+        name="ssd_scan",
+    )(xt, dtt, a.astype(jnp.float32), bt, ct, h0)
+    return y.transpose(0, 2, 1, 3), ht

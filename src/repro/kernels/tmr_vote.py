@@ -8,26 +8,21 @@ twice (once to vote, once to compare).  The kernel fuses vote + three
 compares + count into a single pass: 3 reads + 1 write per word.
 
 Operates on uint32 words; ``ops.py`` flattens/bitcasts arbitrary state
-pytrees.  Counts are emitted per grid block and reduced by the wrapper
-(deterministic integer sums).
+pytrees.  Counts accumulate lane-wise across the grid and the wrapper
+folds them (deterministic integer sums; see ``state_hash.stream_call``).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.compat import pallas_tpu_compiler_params
-from jax.experimental import pallas as pl
+from .state_hash import SUM, stream_call
 
 
-def _vote_kernel(a_ref, b_ref, c_ref, voted_ref, counts_ref):
-    a, b, c = a_ref[...], b_ref[...], c_ref[...]
+def _vote_slab(words, _idx):
+    a, b, c = words
     v = (a & b) | (a & c) | (b & c)
-    voted_ref[...] = v
-    counts_ref[0, 0] = jnp.sum((a != v).astype(jnp.int32))
-    counts_ref[0, 1] = jnp.sum((b != v).astype(jnp.int32))
-    counts_ref[0, 2] = jnp.sum((c != v).astype(jnp.int32))
-    counts_ref[0, 3] = jnp.int32(0)
+    return v, [(r != v).astype(jnp.int32) for r in (a, b, c)]
 
 
 def tmr_vote(
@@ -42,26 +37,8 @@ def tmr_vote(
     """
     assert a.ndim == 1 and a.shape == b.shape == c.shape
     assert a.dtype == jnp.uint32
-    n = a.shape[0]
-    block = min(block, n)
-    assert n % block == 0, (n, block)
-    g = n // block
-    a2, b2, c2 = (r.reshape(g, block) for r in (a, b, c))
-    voted, partial = pl.pallas_call(
-        _vote_kernel,
-        grid=(g,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))] * 3,
-        out_specs=[
-            pl.BlockSpec((1, block), lambda i: (i, 0)),
-            pl.BlockSpec((1, 4), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((g, block), jnp.uint32),
-            jax.ShapeDtypeStruct((g, 4), jnp.int32),
-        ],
-        compiler_params=pallas_tpu_compiler_params(
-            dimension_semantics=("arbitrary",),
-        ),
-        interpret=interpret,
-    )(a2, b2, c2)
-    return voted.reshape(n), jnp.sum(partial, axis=0)[:3]
+    voted, counts = stream_call(
+        _vote_slab, [a, b, c], block=block, acc=[(SUM, jnp.int32)] * 3,
+        with_out=True, interpret=interpret,
+    )
+    return voted, jnp.stack(counts)

@@ -15,23 +15,25 @@ The ``pod`` axis has two personalities, selected by the run config:
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 (explicit-sharding mode); older jax has no AxisType
-    from jax.sharding import AxisType
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 from repro.distributed.sharding import ShardCtx
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis Auto.
+
+    ``jax.make_mesh`` defaults to Explicit axes, under which the program's
+    ``with_sharding_constraint`` calls and the slot writes of spatial
+    serving are refused; every mesh in the repo is built here instead."""
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(
-        shape, axes, axis_types=(AxisType.Auto,) * len(axes)
-    )
+    return make_mesh(shape, axes)
 
 
 def make_ctx(

@@ -51,6 +51,8 @@ from repro import api as miso
 from repro.configs import get_config, get_reduced
 from repro.core import RedundancyPolicy
 from repro.distributed.sharding import LOCAL
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.models.lm_cells import (
     ServeConfig,
@@ -129,6 +131,7 @@ def main():
                     choices=["none", "dmr", "tmr"],
                     help="static path: cell-level policy on the decoder")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     if args.static:
@@ -141,7 +144,7 @@ def main():
 # continuous-batching engine path
 # ===========================================================================
 def engine_main(cfg, args):
-    from repro.serving import DONE, RUNNING, Request
+    from repro.serving import DONE, Request
     from repro.serving.lm import lm_engine_parts
 
     spec = None
@@ -160,7 +163,7 @@ def engine_main(cfg, args):
         if args.slots % pods:
             raise SystemExit(
                 f"--slots {args.slots} must be a multiple of --pods {pods}")
-        mesh = jax.make_mesh((pods, n_dev // pods), ("pod", "data"))
+        mesh = make_mesh((pods, n_dev // pods), ("pod", "data"))
     scfg = ServeConfig(batch=args.slots, max_len=args.max_len,
                        prefill_chunk=args.prefill_chunk,
                        prefill_bucket_min=args.prefill_bucket_min,
@@ -205,47 +208,7 @@ def engine_main(cfg, args):
     if args.strike:
         if victim is None:
             raise SystemExit("--strike needs a dmr request in --mix")
-        # tick until the victim is resident with decode budget left, then
-        # arm a flip against its SECOND replica slot.  The flip fires one
-        # tick after the arming tick, and a speculative tick commits up to
-        # spec_k+1 tokens, so the victim needs that much budget headroom
-        # to still be resident when the strike lands (--spec-k --strike
-        # therefore wants --decode comfortably above 2*(spec_k+1)).
-        margin = args.spec_k + 2
-        rec = engine.requests[victim.id]
-        for _ in range(10 * args.decode):
-            if (rec.status == RUNNING
-                    and len(rec.tokens) + margin <= victim.max_new_tokens):
-                break
-            engine.pump(max_ticks=1)
-        if rec.status != RUNNING:
-            raise SystemExit("strike victim never became resident")
-        from repro.models.lm_cells import (
-            paged_serving_supported,
-            paged_slot_decoder_init,
-            resolve_draft_config,
-            slot_decoder_init,
-            spec_serving_supported,
-        )
-
-        # the flip targets the "tokens" leaf by FLAT INDEX: flatten the
-        # same state layout the engine runs (paged trees order differently,
-        # and a spec engine's decoder carries extra speculation leaves)
-        dcfg, dlen = None, 0
-        if spec is not None and spec_serving_supported(cfg):
-            dcfg, dlen = resolve_draft_config(cfg, spec), spec.draft_len
-        if args.paged and paged_serving_supported(cfg):
-            example = paged_slot_decoder_init(
-                cfg, 2, args.max_len, args.page_size, 1, dcfg, dlen)
-        else:
-            example = slot_decoder_init(cfg, 2, args.max_len, dcfg, dlen)
-        flat, _ = jax.tree_util.tree_flatten_with_path(example)
-        leaf_i = next(i for i, (p, _) in enumerate(flat)
-                      if any(getattr(q, "key", None) == "tokens" for q in p))
-        fault = miso.FaultSpec.at(
-            step=engine.exe.metrics()["steps"] + 1,
-            cell_id=prog.cell_id("decoder"), leaf=leaf_i,
-            index=rec.slots[1], bit=4)
+        fault = arm_strike(engine, cfg, scfg, victim)
     engine.pump(faults=fault)
     wall = time.time() - t0
 
@@ -308,6 +271,57 @@ def engine_main(cfg, args):
         with open(args.metrics_json, "w", encoding="utf-8") as f:
             json.dump(engine.registry.snapshot(), f, indent=1)
         print(f"metrics snapshot -> {args.metrics_json}")
+
+
+def arm_strike(engine, cfg, scfg, victim):
+    """Tick ``engine`` until ``victim`` (a DMR request) is resident with
+    decode budget left, then return a ``FaultSpec`` that flips one bit of
+    the "tokens" leaf in its SECOND replica slot on the next step.
+
+    The flip fires one tick after the arming tick, and a speculative tick
+    commits up to spec_k+1 tokens, so the victim needs that much budget
+    headroom to still be resident when the strike lands (a speculating
+    engine therefore wants ``max_new_tokens`` comfortably above
+    2*(spec_k+1))."""
+    from repro.models.lm_cells import (
+        paged_serving_supported,
+        paged_slot_decoder_init,
+        resolve_draft_config,
+        slot_decoder_init,
+        spec_serving_supported,
+    )
+    from repro.serving import RUNNING
+
+    spec = scfg.spec
+    spec_k = spec.draft_len if spec is not None else 0
+    rec = engine.requests[victim.id]
+    for _ in range(10 * victim.max_new_tokens):
+        if (rec.status == RUNNING
+                and len(rec.tokens) + spec_k + 2 <= victim.max_new_tokens):
+            break
+        engine.pump(max_ticks=1)
+    if rec.status != RUNNING:
+        raise SystemExit("strike victim never became resident")
+
+    # the flip targets the "tokens" leaf by FLAT INDEX: flatten the same
+    # state layout the engine runs (paged trees order differently, and a
+    # spec engine's decoder carries extra speculation leaves)
+    dcfg, dlen = None, 0
+    if spec is not None and spec_serving_supported(cfg):
+        dcfg, dlen = resolve_draft_config(cfg, spec), spec.draft_len
+    if scfg.paged and paged_serving_supported(cfg):
+        example = jax.eval_shape(lambda: paged_slot_decoder_init(
+            cfg, 2, scfg.max_len, scfg.page_size, 1, dcfg, dlen))
+    else:
+        example = jax.eval_shape(lambda: slot_decoder_init(
+            cfg, 2, scfg.max_len, dcfg, dlen))
+    flat, _ = jax.tree_util.tree_flatten_with_path(example)
+    leaf_i = next(i for i, (p, _) in enumerate(flat)
+                  if any(getattr(q, "key", None) == "tokens" for q in p))
+    return miso.FaultSpec.at(
+        step=engine.exe.metrics()["steps"] + 1,
+        cell_id=engine.exe.program.cell_id("decoder"), leaf=leaf_i,
+        index=rec.slots[1], bit=4)
 
 
 # ===========================================================================

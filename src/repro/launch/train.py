@@ -29,6 +29,7 @@ from repro.configs import get_config, get_reduced
 from repro.core import FaultLedger, FaultSpec, RedundancyPolicy
 from repro.data.pipeline import DataConfig, bigram_optimal_xent
 from repro.distributed.sharding import LOCAL
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.lm_cells import TrainConfig, make_train_program
 from repro.optim.adamw import OptConfig
 
@@ -86,6 +87,7 @@ def main():
     ap.add_argument("--log-every", type=int, default=5)
     ap.add_argument("--log-file", default="")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg, tcfg, prog = build(args)
     prog.validate()

@@ -23,7 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.core import CellType, MisoProgram
 from repro.data.pipeline import DataConfig, data_cell
@@ -287,7 +287,7 @@ class ServeConfig:
     #: fixed-size pages of one shared pool (``serving/paging.py``) instead
     #: of a dense per-slot ``max_len`` allocation, so admission is bounded
     #: by free *pages*, not free dense bytes.  Recurrent and windowed
-    #: archs silently fall back to dense (``paged_serving_supported``).
+    #: archs fall back to dense with a warning (``paged_serving_supported``).
     paged: bool = False
     #: tokens per KV page; ``max_len`` must be a multiple of it.
     page_size: int = 16

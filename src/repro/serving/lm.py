@@ -28,6 +28,7 @@ The engine surfaces ``prefill_compiles`` / ``prefill_buckets`` in
 
 from __future__ import annotations
 
+import warnings
 from typing import Optional
 
 import jax
@@ -61,8 +62,12 @@ def lm_engine_parts(cfg: ModelConfig, scfg: ServeConfig, ctx: ShardCtx = LOCAL):
     keeps working.)"""
     prog = make_slot_serve_program(cfg, scfg, ctx)
     # paged KV: same gate the program builder uses — unsupported archs
-    # silently keep the dense cache (mirrors the bucket carve-outs below)
+    # keep the dense cache (mirrors the bucket carve-outs below), and say so
     paged = scfg.paged and paged_serving_supported(cfg)
+    if scfg.paged and not paged:
+        warnings.warn(
+            f"{cfg.name}: the paged KV cache needs an attention-only, "
+            "unwindowed model; serving from the dense cache", stacklevel=2)
     # speculative decoding: same silent-fallback pattern — archs that
     # cannot roll the cache position back keep plain decode, and any
     # per-request spec ask is then ignored (docs/serving.md)

@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.jit import forwarding_jit
 from repro.core.redundancy import bit_mismatch_elems
 
 from .slots import SlotSurgery, _bcast, read_slot, slot_fingerprints
@@ -248,6 +249,19 @@ def paged_view(dec: dict, pages: Optional[jax.Array] = None) -> dict:
     return view
 
 
+def paged_read_slot(dec: dict, slot: jax.Array, vaxes: Pytree) -> dict:
+    """``read_slot(paged_view(dec), slot, vaxes)``, gathering only that
+    slot's pages instead of every slot's."""
+    rest = {k: v for k, v in dec.items() if k not in ("cache", "pages")}
+    one = read_slot(rest, slot, {k: vaxes[k] for k in rest})
+    one["cache"] = {
+        "segments": dec["cache"]["segments"],
+        "pos": read_slot(dec["cache"]["pos"], slot, vaxes["cache"]["pos"]),
+    }
+    one["pages"] = jax.lax.dynamic_slice_in_dim(dec["pages"], slot, 1, axis=0)
+    return paged_view(one)
+
+
 def view_axes_of(axes: Pytree) -> Pytree:
     """Slot axes of ``paged_view``'s output: gathered cache leaves carry
     the slot axis at 1 (dense stacked layout); everything else keeps its
@@ -403,22 +417,25 @@ def paged_surgery(
                 new[k] = _put(v, _take(odec[k], slot, axes[k]), slot, axes[k])
         return {**st, cell: new}
 
-    jit_install = jax.jit(_install)
-    jit_scrub = jax.jit(_scrub)
-    jit_copy = jax.jit(_copy)
-    jit_adopt = jax.jit(_adopt)
-    jit_fps = jax.jit(lambda dec: slot_fingerprints(paged_view(dec), vaxes))
+    # forwarding: the cells a slot op leaves alone (the weights) come back
+    # as the same buffers, not copies
+    jit_install = forwarding_jit(_install)
+    jit_scrub = forwarding_jit(_scrub)
+    jit_copy = forwarding_jit(_copy)
+    jit_adopt = forwarding_jit(_adopt)
+    jit_fps = jax.jit(lambda dec: slot_fingerprints(
+        dec, vaxes, n=dec["pages"].shape[0], read=paged_read_slot))
 
     def _damage_impl(st, a, b):
         return bit_mismatch_elems(
-            read_slot(paged_view(st[cell]), a, vaxes),
-            read_slot(paged_view(st[cell]), b, vaxes),
+            paged_read_slot(st[cell], a, vaxes),
+            paged_read_slot(st[cell], b, vaxes),
         )
 
     def _damage_vs_impl(st, other, slot):
         return bit_mismatch_elems(
-            read_slot(paged_view(st[cell]), slot, vaxes),
-            read_slot(paged_view(other[cell]), slot, vaxes),
+            paged_read_slot(st[cell], slot, vaxes),
+            paged_read_slot(other[cell], slot, vaxes),
         )
 
     jit_damage = jax.jit(_damage_impl)
@@ -516,7 +533,7 @@ def make_pre_tick(
         }
         return {**st, cell: new}
 
-    jit_grow = jax.jit(grow)
+    jit_grow = forwarding_jit(grow)
 
     def pre_tick(states):
         dec = states[cell]

@@ -25,6 +25,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core.jit import forwarding_jit
 from repro.core.redundancy import bit_mismatch_elems, fingerprint
 
 Pytree = Any
@@ -102,14 +103,25 @@ def copy_slot(state: Pytree, src: jax.Array, dst: jax.Array, axes: Pytree) -> Py
     return join_slot(state, read_slot(state, src, axes), dst, axes)
 
 
-def slot_fingerprints(state: Pytree, axes: Pytree) -> jax.Array:
+def slot_fingerprints(
+    state: Pytree, axes: Pytree, *, n: Optional[int] = None,
+    read: Callable[[Pytree, jax.Array, Pytree], Pytree] = read_slot,
+) -> jax.Array:
     """(B, 4) uint32: the 128-bit state fingerprint of every slot's view
     of the state.  Replica slots of one request are bitwise-equal by
     construction, so equal fingerprints <=> healthy; the engine compares
     these between ticks to detect (DMR) and localize (TMR) strikes at
-    request granularity, at O(B * 16 bytes) host traffic."""
-    moved = jax.tree.map(lambda x, ax: jnp.moveaxis(x, ax, 0), state, axes)
-    return jax.vmap(fingerprint)(moved)
+    request granularity, at O(B * 16 bytes) host traffic.
+
+    Slots are hashed one at a time, each from its width-1 view
+    ``read(state, slot, axes)``, so the working set is one slot's view,
+    not a widened copy of the whole batch.  ``n`` is the slot count
+    (default: read off the first leaf)."""
+    if n is None:
+        leaf, ax = jax.tree.leaves(state)[0], jax.tree.leaves(axes)[0]
+        n = leaf.shape[ax]
+    return jax.lax.map(lambda s: fingerprint(read(state, s, axes)),
+                       jnp.arange(n, dtype=jnp.int32))
 
 
 # --------------------------------------------------------------------------
@@ -149,10 +161,12 @@ def default_surgery(
     """Dense-layout surgery: every leaf is whole-per-slot, so join/copy/
     adopt are the pure helpers above, jitted once with ``axes`` closed
     over (traced slot indices — one compile covers every slot)."""
-    _join = jax.jit(
+    # forwarding: the cells a slot op leaves alone (the weights) come back
+    # as the same buffers, not copies
+    _join = forwarding_jit(
         lambda st, ss, slot: {**st, cell: join_slot(st[cell], ss, slot, axes)}
     )
-    _copy = jax.jit(
+    _copy = forwarding_jit(
         lambda st, src, dst: {**st, cell: copy_slot(st[cell], src, dst, axes)}
     )
 
@@ -160,7 +174,7 @@ def default_surgery(
         taken = read_slot(other[cell], slot, axes)
         return {**st, cell: join_slot(st[cell], taken, slot, axes)}
 
-    _adopt = jax.jit(_adopt_impl)
+    _adopt = forwarding_jit(_adopt_impl)
     _fps = jax.jit(lambda dec: slot_fingerprints(dec, axes))
 
     # real damage accounting: mismatched ELEMENTS between two replica

@@ -26,10 +26,10 @@ import numpy as np
 
 from repro.configs import get_reduced
 from repro.distributed.sharding import LOCAL, ShardCtx
-from repro.launch.mesh import make_ctx
+from repro.launch.mesh import make_ctx, make_mesh
 from repro.models import transformer as T
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 
 def run_case(arch, ep2d=False, **over):
     cfg = get_reduced(arch)

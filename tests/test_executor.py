@@ -745,3 +745,49 @@ def test_cell_id_lookup():
     # with_policies rebuilds the program; ids must follow
     prog2 = prog.with_policies({"b": miso.RedundancyPolicy(level=2)})
     assert prog2.cell_id("b") == 1
+
+
+# ---------------------------------------------------------------------------
+# pass-through cells keep their buffers (no per-step copy of static state)
+# ---------------------------------------------------------------------------
+def static_cell_program():
+    p = miso.MisoProgram()
+    p.add(miso.CellType("w", lambda k: {"m": jnp.arange(16, dtype=jnp.float32)},
+                        lambda prev: prev["w"]))
+    p.add(miso.CellType("a", lambda k: {"x": jnp.zeros((16,), jnp.float32)},
+                        lambda prev: {"x": prev["a"]["x"] + prev["w"]["m"]},
+                        reads=("w",), redundancy=miso.RedundancyPolicy(level=2)))
+    return p
+
+
+@pytest.mark.parametrize("backend", ["lockstep", "lockstep_pallas"])
+def test_step_forwards_static_cell_buffers(backend):
+    prog = static_cell_program()
+    exe = miso.compile(prog, backend=backend)
+    s0 = exe.init(jax.random.PRNGKey(0))
+    s1, _ = exe.step(s0)
+    assert s1["w"]["m"] is s0["w"]["m"]
+    # a strike armed on another cell still leaves the static cell alone
+    strike = miso.FaultSpec.at(step=1, cell_id=prog.cell_id("a"), replica=1,
+                               index=3, bit=2)
+    s2, rep = exe.step(s1, fault=strike)
+    assert s2["w"]["m"] is s0["w"]["m"]
+    assert float(rep["a"]["events"]) == 1.0
+    # the same compiled step, re-armed at a later step, does not retrace
+    s3, rep = exe.pure_step(s2, 2, fault=miso.FaultSpec.at(
+        step=2, cell_id=prog.cell_id("a"), replica=0, index=5, bit=1))
+    assert float(rep["a"]["events"]) == 1.0
+    # a strike on the static cell itself must land (it is unprotected)
+    s4, _ = exe.step(s3, step_idx=3, fault=miso.FaultSpec.at(
+        step=3, cell_id=prog.cell_id("w"), index=4, bit=30))
+    assert s4["w"]["m"] is not s0["w"]["m"]
+    assert not np.array_equal(s4["w"]["m"], s0["w"]["m"])
+
+
+def test_fault_spec_static_cells():
+    assert miso.FaultSpec.none().cells == ()
+    assert miso.FaultSpec.at(step=0, cell_id=2).cells == (2,)
+    traced = jax.jit(lambda c: miso.FaultSpec.at(step=0, cell_id=c).cells)
+    assert traced(jnp.int32(2)) is None
+    assert miso.FaultSpec.at(step=0, cell_id=np.int32(1)).may_strike(1)
+    assert not miso.FaultSpec.none().may_strike(0)

@@ -11,8 +11,6 @@ hypothesis = pytest.importorskip(
     "hypothesis", reason="property tests need hypothesis (dev extra)")
 from hypothesis import given, settings, strategies as st
 
-from jax.experimental import enable_x64
-
 from repro.kernels import ops
 
 DTYPES = ("bool", "bfloat16", "float32", "int64")
@@ -40,7 +38,7 @@ def _leaf(rng: np.random.Generator, dtype: str, shape: tuple[int, ...]):
 )
 def test_flatten_unflatten_roundtrip(dtypes, shapes, multiple, seed):
     rng = np.random.default_rng(seed)
-    with enable_x64():  # i64 leaves survive only with x64 enabled
+    with jax.enable_x64(True):  # i64 leaves survive only with x64 enabled
         tree = {
             f"leaf{i}": _leaf(rng, dt, tuple(shapes[i]))
             for i, dt in enumerate(dtypes)

@@ -101,11 +101,11 @@ import numpy as np
 
 from repro.configs import get_reduced
 from repro.distributed.sharding import LOCAL
-from repro.launch.mesh import make_ctx
+from repro.launch.mesh import make_ctx, make_mesh
 from repro.models.config import MoEConfig
 from repro.models import moe as M
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = get_reduced("granite-moe-1b-a400m")
 cfg = dataclasses.replace(
     cfg, moe=MoEConfig(n_experts=8, top_k=2, d_ff_expert=16,

@@ -382,6 +382,45 @@ def test_paged_dmr_strike_detected_attributed_repaired():
     assert eng.ledger.totals[req.id]["per_replica"][1] == 1.0
 
 
+@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
+def test_engine_keeps_one_weights_buffer_through_strike_repair(paged):
+    """Joins, ticks, a DMR strike's replay and repair: the static weights
+    cell is never copied — every state the engine holds shares the
+    buffers ``start()`` made (one copy of the weights in device memory).
+    The per-slot fingerprints equal a whole-batch hash of the same view."""
+    from repro.core.redundancy import fingerprint
+    from repro.launch.serve import arm_strike
+    from repro.serving.paging import paged_read_slot, paged_view, view_axes_of
+    from repro.serving.slots import slot_fingerprints
+
+    cfg, scfg = tiny_lm()
+    scfg = paged_cfg(scfg) if paged else scfg
+    eng = lm_engine(cfg, scfg)
+    weights = jax.tree.leaves(eng._states["weights"])
+    pol = miso.RedundancyPolicy(level=2)
+    reqs = [Request(prompt=np.arange(3 + i, dtype=np.int32), max_new_tokens=8,
+                    policy=pol) for i in range(2)]
+    for r in reqs:
+        assert eng.submit(r)
+    fault = arm_strike(eng, cfg, scfg, reqs[0])
+    eng.pump(faults=fault)
+    assert eng.result(reqs[0].id)["faults"] == 1
+    assert all(a is b for a, b in zip(jax.tree.leaves(eng._states["weights"]),
+                                      weights))
+
+    dec = eng._states[eng.adapter.cell]
+    axes = eng.adapter.slot_axes
+    if paged:
+        view, vaxes = paged_view(dec), view_axes_of(axes)
+        got = slot_fingerprints(dec, vaxes, n=dec["pages"].shape[0],
+                                read=paged_read_slot)
+    else:
+        view, vaxes = dec, axes
+        got = slot_fingerprints(dec, axes)
+    moved = jax.tree.map(lambda x, ax: jnp.moveaxis(x, ax, 0), view, vaxes)
+    assert jnp.array_equal(got, jax.vmap(fingerprint)(moved))
+
+
 def test_paged_chunked_prefill_walks_k_tokens_per_tick():
     """``prefill_chunk > 1`` drains k pending prompt tokens per resident
     tick (not one), and the chunked+paged run stays bitwise-equal to the
@@ -439,13 +478,14 @@ def test_paged_admission_waits_for_free_pages_then_completes():
 
 def test_recurrent_arch_silently_falls_back_to_dense():
     """mamba2 has no paged KV (recurrent state, not a token cache):
-    ``paged=True`` degrades to the dense path and still serves."""
+    ``paged=True`` degrades to the dense path, warns, and still serves."""
     from repro.configs import get_reduced
     from repro.models.lm_cells import ServeConfig, paged_serving_supported
 
     cfg = get_reduced("mamba2-2.7b")
     assert not paged_serving_supported(cfg)
-    eng = lm_engine(cfg, ServeConfig(batch=2, max_len=16, paged=True))
+    with pytest.warns(UserWarning, match="serving from the dense cache"):
+        eng = lm_engine(cfg, ServeConfig(batch=2, max_len=16, paged=True))
     req = Request(prompt=np.arange(4, dtype=np.int32), max_new_tokens=3)
     assert eng.submit(req)
     eng.pump()

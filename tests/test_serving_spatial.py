@@ -18,10 +18,10 @@ import pathlib
 import subprocess
 import sys
 
-import jax
 import pytest
 
 from repro import api as miso
+from repro.launch.mesh import make_mesh
 
 _CHILD = r"""
 import os
@@ -31,6 +31,7 @@ import json
 import jax, jax.numpy as jnp
 
 from repro import api as miso
+from repro.launch.mesh import make_mesh
 from repro.serving import Request, SlotAdapter, infer_slot_axes, mask_slots
 
 SLOTS = 8
@@ -98,7 +99,7 @@ def x_leaf_index():
 
 def drive(placement, strike_level):
     spatial = placement == "spatial"
-    mesh = (jax.make_mesh((PODS, 8 // PODS), ("pod", "data"))
+    mesh = (make_mesh((PODS, 8 // PODS), ("pod", "data"))
             if spatial else None)
     prog, adapter = parts(spatial)
     eng = miso.serve(prog, adapter,
@@ -226,5 +227,5 @@ def test_spatial_engine_requires_mesh_and_divisible_slots():
     """Config-time errors need no multi-device mesh (in-process)."""
     with pytest.raises(ValueError, match="mesh"):
         miso.EngineConfig(placement="spatial")
-    cfg = miso.EngineConfig(placement="spatial", mesh=jax.make_mesh((1,), ("pod",)))
+    cfg = miso.EngineConfig(placement="spatial", mesh=make_mesh((1,), ("pod",)))
     assert cfg.backend == "spatial_lockstep"  # auto-upgrade from lockstep

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro import api as miso
+from repro.launch.mesh import make_mesh
 
 _CHILD = r"""
 import os
@@ -32,6 +33,7 @@ from jax.sharding import Mesh
 
 from repro import api as miso
 from repro.ft import elastic
+from repro.launch.mesh import make_mesh
 
 
 def replicated_program(level, compare, placement="spatial"):
@@ -54,7 +56,7 @@ def replicated_program(level, compare, placement="spatial"):
 
 def mesh_for(level):
     if level == 2:
-        return jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        return make_mesh((2, 2, 2), ("pod", "data", "model"))
     devs = np.array(jax.devices()[:6]).reshape(3, 2, 1)
     return Mesh(devs, ("pod", "data", "model"))
 
@@ -316,21 +318,21 @@ def test_spatial_requires_mesh():
 
 
 def test_spatial_requires_pod_axis():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     with pytest.raises(ValueError, match="no 'pod' axis"):
         miso.compile(spatial_program(), backend="spatial_lockstep",
                      mesh=mesh)
 
 
 def test_spatial_requires_matching_pod_count():
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
     with pytest.raises(ValueError, match="must match"):
         miso.compile(spatial_program(level=2), backend="spatial_lockstep",
                      mesh=mesh)
 
 
 def test_spatial_requires_spatial_cells():
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
     prog = miso.MisoProgram()
     prog.add(miso.CellType(
         "a", lambda k: {"x": jnp.ones((4,), jnp.float32)},
@@ -347,7 +349,7 @@ def test_make_spatial_ctx_constrains_nothing_inside_manual_body():
     region would reject, and the pod axis never carries data."""
     from repro.launch.mesh import make_spatial_ctx
 
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = make_mesh((1, 1, 1), ("pod", "data", "model"))
     ctx = make_spatial_ctx(mesh)
     assert ctx.data_axes == ("data",)          # pod holds replicas
     assert ctx.manual_axes == ("pod", "data", "model")
@@ -357,11 +359,14 @@ def test_make_spatial_ctx_constrains_nothing_inside_manual_body():
 
 def test_auto_does_not_pick_spatial_without_fitting_mesh():
     """auto only resolves to the spatial back-end when the mesh can place
-    one replica per pod; otherwise the policy stays a temporal request."""
-    mesh = jax.make_mesh((1,), ("pod",))
-    exe = miso.compile(spatial_program(level=2), backend="auto", mesh=mesh)
+    one replica per pod; otherwise the policy stays a temporal request,
+    with a warning."""
+    mesh = make_mesh((1,), ("pod",))
+    with pytest.warns(UserWarning, match="run temporally"):
+        exe = miso.compile(spatial_program(level=2), backend="auto", mesh=mesh)
     assert exe.name == "lockstep"
-    assert miso.compile(spatial_program(2), backend="auto").name == "lockstep"
+    with pytest.warns(UserWarning, match="run temporally"):
+        assert miso.compile(spatial_program(2), backend="auto").name == "lockstep"
 
 
 # ---------------------------------------------------------------------------
@@ -388,7 +393,7 @@ def test_spatial_init_places_replicas_on_pods():
         "b", lambda k: {"x": jnp.ones((8,), jnp.float32)},
         lambda prev: {"x": prev["b"]["x"] * 0.5 + prev["a"]["x"]},
         reads=("a",)))
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     exe = miso.compile(prog, backend="spatial_lockstep", mesh=mesh)
     states = exe.init(jax.random.PRNGKey(0))
     assert states["a"]["x"].shape == (2, 8)   # replica axis
@@ -415,7 +420,7 @@ def test_auto_mixed_spatial_levels_fall_back_to_temporal():
         lambda prev: {"x": prev["b"]["x"] * 0.5 + prev["a"]["x"] * 0.25},
         reads=("a",),
         redundancy=miso.RedundancyPolicy(level=3, placement="spatial")))
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     exe = miso.compile(prog, backend="auto", mesh=mesh)
     assert exe.name == "lockstep"
     exe.run(exe.init(jax.random.PRNGKey(0)), 2)   # and it runs
@@ -429,7 +434,7 @@ def test_auto_resolves_spatial_on_pod_mesh():
         lambda prev: {"x": prev["a"]["x"] * 0.5},
         redundancy=miso.RedundancyPolicy(level=2, placement="spatial",
                                          compare="hash")))
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     exe = miso.compile(prog, backend="auto", mesh=mesh)
     assert exe.name == "spatial_lockstep"
     res = exe.run(exe.init(jax.random.PRNGKey(0)), 3, start_step=0)
