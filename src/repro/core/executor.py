@@ -198,6 +198,10 @@ class Executor:
         #: so no event dicts are allocated and no clocks are read.
         #: ``Tracer.executor_hook()`` adapts this into trace events.
         self.on_event = on_event
+        #: ``(name) -> context manager`` wrapping the serving stream's
+        #: step and its blocking reports read in named host spans; the
+        #: serving engine sets it when it has a tracer.  None = no span.
+        self.span_hook: Optional[Callable[[str], Any]] = None
         #: checkpointing is part of the base protocol: ``run``/``stream``
         #: hand the cb the consistent pre-step buffer every
         #: ``checkpoint_every`` steps (MISO's double buffering makes the
@@ -395,8 +399,12 @@ class Executor:
                 if swapped is not None:
                     states = swapped
             self._maybe_checkpoint(t, states)
-            states, rep = self.step(
-                states, step_idx=t, fault=_fault_in_window(flist, t, stride))
+            fault = _fault_in_window(flist, t, stride)
+            if self.span_hook is not None:
+                with self.span_hook("step"):
+                    states, rep = self.step(states, step_idx=t, fault=fault)
+            else:
+                states, rep = self.step(states, step_idx=t, fault=fault)
             yield states, rep
             t += stride
 
@@ -457,10 +465,17 @@ class Executor:
     def _ledger_update(self, step: int, reports: dict) -> None:
         if _is_traced(reports):
             return  # inside an outer trace: no host-side accounting
-        host = jax.tree.map(jax.device_get, reports)
+        host = self._fetch_reports(reports)
         self.ledger.update(step, host)
         if self.on_event is not None:
             self._emit_mismatches(step, host)
+
+    def _fetch_reports(self, reports: dict) -> dict:
+        """The step's reports on the host: waits for the step to finish."""
+        if self.span_hook is not None:
+            with self.span_hook("sync.step_reports"):
+                return jax.tree.map(jax.device_get, reports)
+        return jax.tree.map(jax.device_get, reports)
 
     def _emit_mismatches(self, step: int, host_reports: dict) -> None:
         """Surface replica-compare disagreements (caller guards on
@@ -538,7 +553,7 @@ class LockstepExecutor(Executor):
         self.step_fn = step_fn
         # pass-through cells (static weights) come back as the same
         # buffers instead of a fresh copy per step
-        self._jit_step = forwarding_jit(step_fn)
+        self._jit_step = forwarding_jit(step_fn, name="lockstep_step")
         self._jit_plain_window = None   # lazy: pure_step(compare=False)
         self._run_cache: dict = {}
 
@@ -572,7 +587,8 @@ class LockstepExecutor(Executor):
                         states, reports = plain(states, step_idx + j, fault)
                     return states, reports
 
-                self._jit_plain_window = forwarding_jit(window)
+                self._jit_plain_window = forwarding_jit(
+                    window, name="lockstep_plain_window")
             with self._mesh_ctx():
                 return self._jit_plain_window(
                     states, jnp.int32(int(step_idx)), fault)
@@ -789,7 +805,7 @@ class HostExecutor(Executor):
         fault = fault if fault is not None else FaultSpec.none()
         with self._mesh_ctx():
             states, reports = self._step(prev, jnp.int32(t), fault)
-        host_reports = jax.tree.map(jax.device_get, reports)
+        host_reports = self._fetch_reports(reports)
         self.ledger.update(t, host_reports)
         if self.on_event is not None:
             self._emit_mismatches(t, host_reports)

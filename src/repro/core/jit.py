@@ -6,9 +6,21 @@ from typing import Callable
 import jax
 
 
-def forwarding_jit(fn: Callable) -> Callable:
+def named(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name``: the name ``jax.jit`` gives its program
+    (``jit_<name>`` in a profile)."""
+
+    def call(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    call.__name__ = call.__qualname__ = name
+    return call
+
+
+def forwarding_jit(fn: Callable, *, name: str) -> Callable:
     """``jax.jit(fn)``, except that every output leaf ``fn`` returns
     unchanged from its inputs comes back as the input array itself.
+    The compiled program is called ``name``.
 
     A plain jit writes every output to a fresh buffer.  A program's static
     cells (the serving weights) and the cells a slot operation leaves alone
@@ -32,7 +44,7 @@ def forwarding_jit(fn: Callable) -> Callable:
                 return [o for o, f in zip(outs, fwd) if f is None]
 
             plan = plans[key] = (fwd, jax.tree.structure(out_shape),
-                                 jax.jit(computed))
+                                 jax.jit(named(computed, name)))
         fwd, out_tree, jitted = plan
         got = iter(jitted(*args))
         return jax.tree.unflatten(

@@ -19,6 +19,10 @@ Design constraints (docs/observability.md):
     clock is read, and (for the serving engine) the emitted tokens are
     bitwise-identical with and without a tracer attached (gated in
     tests/test_obs.py).
+  * **One clock with the device.**  Every ``span`` also opens a
+    ``jax.profiler.TraceAnnotation`` of the same name for its length,
+    so while a ``jax.profiler`` session runs the program's spans land
+    on the host line of its capture, beside the device's operations.
   * **Bounded when present.**  Events append to a ``deque(maxlen=...)``
     ring: a long-running server traces forever in O(capacity) host
     memory; the oldest events fall off.  ``dropped`` counts evictions.
@@ -38,7 +42,6 @@ from detection into the repair.
 from __future__ import annotations
 
 import collections
-import contextlib
 import itertools
 import json
 import time
@@ -58,11 +61,12 @@ class Tracer:
     Emission API (all host-side, all O(1)):
 
       begin(name, track, **args) / end(track, name)   -- B/E span pair
-      complete(name, track, ts_us, dur_us, **args)    -- X span (measured)
+      span(name, track, **args)                       -- X span (measured)
+      complete(name, track, ts_us, dur_us, **args)    -- X span (bracketed
+                                                         by the caller)
       instant(name, track, **args)                    -- i event
       flow_id() ; flow_start(fid, track, name)        -- s/f flow arrow
                   flow_end(fid, track, name)
-      counter(name, track, **values)                  -- C series
 
     ``track`` is a string lane name ("engine", a request id, ...);
     thread ids are interned on first use and exported as
@@ -85,6 +89,9 @@ class Tracer:
         self._tids: dict[str, int] = {}
         self._flow_ids = itertools.count(1)
         self.emitted = 0  # total events ever appended (>= len(ring))
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
 
     # -- clock / track interning ------------------------------------------
     def now_us(self) -> float:
@@ -128,18 +135,11 @@ class Tracer:
     def instant(self, name: str, track: str, **args: Any) -> None:
         self._event("i", name, track, ts=self.now_us(), s="t", args=args)
 
-    def counter(self, name: str, track: str, **values: float) -> None:
-        """A counter sample (Perfetto renders a value track)."""
-        self._event("C", name, track, ts=self.now_us(), args=values)
-
-    @contextlib.contextmanager
-    def span(self, name: str, track: str, **args: Any):
-        """Bracket a host-side block as one measured X span."""
-        t0 = self.now_us()
-        try:
-            yield
-        finally:
-            self.complete(name, track, t0, self.now_us() - t0, **args)
+    def span(self, name: str, track: str, **args: Any) -> "Span":
+        """A measured X span of a host-side block, mirrored by a profiler
+        annotation of the same name: ``with tracer.span(...) as sp:``, or
+        ``start()`` / ``stop()`` for a span that outlives one call."""
+        return Span(self, name, track, args)
 
     # -- flow arrows (strike -> repair) ------------------------------------
     def flow_id(self) -> int:
@@ -226,6 +226,34 @@ class Tracer:
         with open(path, "w", encoding="utf-8") as f:
             json.dump(self.to_chrome_trace(), f)
             f.write("\n")
+
+
+class Span:
+    """One measured X span (``Tracer.span``).  ``args`` may grow until the
+    span closes; they are emitted with it."""
+
+    __slots__ = ("_tracer", "name", "track", "args", "ts", "_ann")
+
+    def __init__(self, tracer: Tracer, name: str, track: str, args: dict):
+        self._tracer = tracer
+        self.name = name
+        self.track = track
+        self.args = args
+
+    def start(self) -> "Span":
+        self._ann = self._tracer._annotation(self.name)
+        self.ts = self._tracer.now_us()
+        return self
+
+    def stop(self) -> None:
+        dur = self._tracer.now_us() - self.ts
+        self._ann.__exit__(None, None, None)
+        self._tracer.complete(self.name, self.track, self.ts, dur, **self.args)
+
+    __enter__ = start
+
+    def __exit__(self, *exc) -> None:
+        self.stop()
 
 
 #: convenience: producers type their slot as ``Optional[Tracer]``

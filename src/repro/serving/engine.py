@@ -201,9 +201,9 @@ class SlotAdapter:
                    replicated admissions never defragment.
     attach_tracer -- optional ``(tracer) -> None``: hand the engine's
                    tracer to adapter-side closures that emit their own
-                   events (the paged ``pre_tick`` traces demand-map page
-                   faults).  Called once by the engine when a tracer is
-                   attached; never called when tracing is off.
+                   events and spans (the paged ``pre_tick`` and join).
+                   Called once by the engine when a tracer is attached;
+                   never called when tracing is off.
     """
 
     cell: str
@@ -249,6 +249,9 @@ class RequestRecord:
     #: track (must be closed before the lifecycle span can end — B/E
     #: events nest as a stack per track)
     trace_walk_open: bool = False
+    #: tracing only: the "queue_wait" span, open from submission until
+    #: admission (or until the request leaves the queue unserved)
+    trace_queue: Any = None
 
     @property
     def id(self) -> str:
@@ -353,6 +356,9 @@ class ServingEngine:
             # adapter closures (paged pre_tick page faults) emit too
             adapter.attach_tracer(tracer)
         self.exe = _ex.compile(program, backend=cfg.backend, **compile_opts)
+        if tracer is not None:
+            # the step and its blocking reports read, as engine spans
+            self.exe.span_hook = lambda name: tracer.span(name, "engine")
         if type(self.exe).pure_step is _ex.Executor.pure_step:
             with_replay = sorted(
                 name
@@ -515,6 +521,8 @@ class ServingEngine:
         rec.status = self.queue.status[req.id]
         if not ok:
             self._finish_record(rec, REJECTED)
+        elif self.tracer is not None:
+            rec.trace_queue = self.tracer.span("queue_wait", req.id).start()
         return ok
 
     def cancel(self, rid: str) -> bool:
@@ -578,38 +586,16 @@ class ServingEngine:
             while True:
                 tick_t0 = self.time_fn()
                 if tr is not None:
-                    ts0 = tr.now_us()
-                try:
-                    # one tick = swap (admit/join) + compiled step dispatch
+                    states = self._traced_tick(tr, stream)
+                else:
+                    # one tick = swap (admit/join) + compiled step
+                    # dispatch (the stream never ends: n_steps is None)
                     states, _reports = next(stream)
-                except StopIteration:
-                    break
-                if tr is not None:
-                    # host-dispatch vs device split: next() returns as
-                    # soon as the step is dispatched; the fence brackets
-                    # the device-side work.  Only done under a tracer —
-                    # the untraced engine never syncs here.
-                    ts1 = tr.now_us()
-                    _fence(states[self.adapter.cell])
-                    ts2 = tr.now_us()
-                    self._trace_tick_ts0 = ts0
-                states = self._postprocess(self._tick_step, states)
+                    states = self._postprocess(self._tick_step, states)
                 self._states = states
                 self._override = states
                 self._m_ticks.inc()
                 self._h_tick.observe(self.time_fn() - tick_t0)
-                if tr is not None:
-                    ts3 = tr.now_us()
-                    tr.complete(
-                        "tick",
-                        "engine",
-                        ts0,
-                        ts3 - ts0,
-                        step=self._tick_step,
-                        dispatch_us=ts1 - ts0,
-                        device_us=ts2 - ts1,
-                        harvest_us=ts3 - ts2,
-                    )
                 ticks += 1
                 if max_ticks is not None and ticks >= max_ticks:
                     break
@@ -618,6 +604,35 @@ class ServingEngine:
         finally:
             stream.close()
         return ticks
+
+    def _traced_tick(self, tr: Tracer, stream) -> dict:
+        """One tick inside a ``tick`` span, split into the swap and step
+        (``dispatch_us``, which ends with the executor's blocking reports
+        read), the rest of the device work (``device_us``) and the
+        harvest (``harvest_us``)."""
+        with tr.span("tick", "engine") as tick:
+            states, _reports = next(stream)
+            ts1 = tr.now_us()
+            _fence(states[self.adapter.cell])
+            ts2 = tr.now_us()
+            self._trace_tick_ts0 = tick.ts
+            with tr.span("postprocess", "engine"):
+                states = self._postprocess(self._tick_step, states)
+            tick.args.update(
+                step=self._tick_step,
+                dispatch_us=ts1 - tick.ts,
+                device_us=ts2 - ts1,
+                harvest_us=tr.now_us() - ts2,
+            )
+        return states
+
+    def _sync(self, name: str, x):
+        """``jax.device_get(x)``: the host waits for the device to finish
+        what ``x`` depends on, inside a ``name`` span when traced."""
+        if self.tracer is None:
+            return jax.device_get(x)
+        with self.tracer.span(name, "engine"):
+            return jax.device_get(x)
 
     def _swap(self, t: int, states: dict) -> dict:
         """The stream's state swap-in hook (pre-tick boundary): apply the
@@ -666,40 +681,55 @@ class ServingEngine:
                 continue  # head expired underneath us: re-validate
             rec = self.requests[req.id]
             if self.tracer is not None:
-                with self.tracer.span("prefill", req.id, prompt_len=req.prompt_len):
-                    out = self.adapter.prefill(req, states)
-                    _fence(out[0])
+                rec.trace_queue.stop()
+                rec.trace_queue = None
+                with self.tracer.span("admit", "engine", rid=req.id):
+                    states = self._join_request(t, states, rec, contig, spatial_req)
             else:
+                states = self._join_request(t, states, rec, contig, spatial_req)
+        return states
+
+    def _join_request(
+        self, t: int, states: dict, rec: RequestRecord, contig: bool, spatial_req: bool
+    ) -> dict:
+        """Prefill an admitted request and join it into its slots."""
+        req = rec.req
+        if self.tracer is not None:
+            with self.tracer.span("prefill", req.id, prompt_len=req.prompt_len):
                 out = self.adapter.prefill(req, states)
-            slot_state, first = out[0], out[1]
-            pending = out[2] if len(out) > 2 else 0
-            slots = self.slots.alloc(
-                req.id, req.n_slots, contiguous=contig, spatial=spatial_req
-            )
-            for s in slots:
-                states = self._ops.join(states, slot_state, s, req=req)
-            now = self.time_fn()
-            rec.slots = slots
-            rec.spatial = spatial_req
-            rec.status = RUNNING
-            rec.started_at = now
-            rec.prefill_remaining = int(pending)
-            if self.tracer is not None:
-                self.tracer.instant("admitted", req.id, step=t, slots=list(slots))
-                if pending:
-                    # chunked prefill: the in-transition walk consumes
-                    # the prompt tail over the next ticks; the span ends
-                    # when prefill_remaining drains (_postprocess)
-                    self.tracer.begin("prefill_walk", req.id, pending=int(pending))
-                    rec.trace_walk_open = True
-            if pending == 0:
-                # the prefill's greedy continuation IS the first emitted
-                # token; with a pending tail the first token arrives when
-                # the in-slot walk drains (_postprocess)
-                self._emit(rec, np.asarray(jax.device_get(first)).reshape(-1), now)
-            status = self._should_finish(rec, now)
-            if status is not None:  # e.g. max_new_tokens == 1
-                states = self._evict(states, rec, status)
+                _fence(out[0])
+        else:
+            out = self.adapter.prefill(req, states)
+        slot_state, first = out[0], out[1]
+        pending = out[2] if len(out) > 2 else 0
+        slots = self.slots.alloc(
+            req.id, req.n_slots, contiguous=contig, spatial=spatial_req
+        )
+        for s in slots:
+            states = self._ops.join(states, slot_state, s, req=req)
+        now = self.time_fn()
+        rec.slots = slots
+        rec.spatial = spatial_req
+        rec.status = RUNNING
+        rec.started_at = now
+        rec.prefill_remaining = int(pending)
+        if self.tracer is not None:
+            self.tracer.instant("admitted", req.id, step=t, slots=list(slots))
+            if pending:
+                # chunked prefill: the in-transition walk consumes
+                # the prompt tail over the next ticks; the span ends
+                # when prefill_remaining drains (_postprocess)
+                self.tracer.begin("prefill_walk", req.id, pending=int(pending))
+                rec.trace_walk_open = True
+        if pending == 0:
+            # the prefill's greedy continuation IS the first emitted
+            # token; with a pending tail the first token arrives when
+            # the in-slot walk drains (_postprocess)
+            first = np.asarray(self._sync("sync.first_token", first))
+            self._emit(rec, first.reshape(-1), now)
+        status = self._should_finish(rec, now)
+        if status is not None:  # e.g. max_new_tokens == 1
+            states = self._evict(states, rec, status)
         return states
 
     def _defrag(self, states: dict, n: int) -> dict:
@@ -725,21 +755,20 @@ class ServingEngine:
         replicated = [r for r in running if r.req.policy.level > 1]
         temporal = [r for r in replicated if not r.spatial]
         spatial = [r for r in replicated if r.spatial]
-        if temporal:
-            states = self._check_replicas(t, states, temporal)
-        if spatial:
-            states = self._check_spatial(t, states, spatial)
+        if replicated:
+            if self.tracer is not None:
+                with self.tracer.span("check_replicas", "engine"):
+                    states = self._check(t, states, temporal, spatial)
+            else:
+                states = self._check(t, states, temporal, spatial)
         if running:
-            toks = np.asarray(
-                jax.device_get(self.adapter.read_tokens(states[self.adapter.cell]))
-            )
+            cell = states[self.adapter.cell]
+            toks = np.asarray(self._sync("sync.tokens", self.adapter.read_tokens(cell)))
             sout = sn = None
             if self.adapter.read_spec is not None:
                 sout, sn = (
                     np.asarray(x)
-                    for x in jax.device_get(
-                        self.adapter.read_spec(states[self.adapter.cell])
-                    )
+                    for x in self._sync("sync.spec", self.adapter.read_spec(cell))
                 )
             now = self.time_fn()
             for rec in running:
@@ -807,11 +836,39 @@ class ServingEngine:
                     states = self._evict(states, rec, status)
         return states
 
+    def _check(self, t: int, states: dict, temporal: list, spatial: list) -> dict:
+        if temporal:
+            states = self._check_replicas(t, states, temporal)
+        if spatial:
+            states = self._check_spatial(t, states, spatial)
+        return states
+
+    def _damage(self, fn: Callable, *args) -> float:
+        """Mismatching elements, by ``fn`` (a surgery ``damage`` or
+        ``damage_vs``), which reads them to the host."""
+        if self.tracer is None:
+            return fn(*args)
+        with self.tracer.span("sync.damage", "engine"):
+            return fn(*args)
+
+    def _adopt(self, states: dict, replay: dict, slots: list[int]) -> dict:
+        """§IV adoption: every replica slot takes the replay's state."""
+        if self.tracer is None:
+            for sl in slots:
+                states = self._ops.adopt(states, replay, sl)
+            return states
+        with self.tracer.span("adopt", "engine"):
+            for sl in slots:
+                states = self._ops.adopt(states, replay, sl)
+        return states
+
     def _check_replicas(self, t: int, states: dict, recs: list[RequestRecord]) -> dict:
         """Compare each replicated request's replica-slot fingerprints;
         attribute mismatches to the owning request and repair."""
         fps = np.asarray(
-            jax.device_get(self._ops.fingerprints(states[self.adapter.cell]))
+            self._sync(
+                "sync.fingerprints", self._ops.fingerprints(states[self.adapter.cell])
+            )
         )
         replay = None  # lazy: one §IV replay serves every event this tick
         for rec in recs:
@@ -841,7 +898,7 @@ class ServingEngine:
                     bad = ({0, 1, 2} - {i, j}).pop()
                     # real damage: elements of the struck replica slot
                     # differing from a majority slot (pre-repair)
-                    dmg = self._ops.damage(states, s[i], s[bad])
+                    dmg = self._damage(self._ops.damage, states, s[i], s[bad])
                     if tr is not None:
                         tr.instant(
                             "strike_attributed",
@@ -871,13 +928,18 @@ class ServingEngine:
                 else:
                     replay, _ = self.exe.pure_step(self._tick_input, t)
                 rfps = np.asarray(
-                    jax.device_get(self._ops.fingerprints(replay[self.adapter.cell]))
+                    self._sync(
+                        "sync.fingerprints",
+                        self._ops.fingerprints(replay[self.adapter.cell]),
+                    )
                 )
             if bad is None:
                 bad = [
                     i for i, sl in enumerate(s) if not np.array_equal(fps[sl], rfps[sl])
                 ]
-            dmg = sum(self._ops.damage_vs(states, replay, s[b]) for b in bad)
+            dmg = sum(
+                self._damage(self._ops.damage_vs, states, replay, s[b]) for b in bad
+            )
             if tr is not None:
                 tr.instant(
                     "strike_attributed",
@@ -886,8 +948,7 @@ class ServingEngine:
                     replicas=list(bad),
                     damage_elems=float(dmg),
                 )
-            for sl in s:
-                states = self._ops.adopt(states, replay, sl)
+            states = self._adopt(states, replay, s)
             self._attribute(rec, t, bad, level, dmg)
             if tr is not None:
                 tr.instant("strike_repaired", rec.id, step=t, repair="dmr_replay")
@@ -926,8 +987,11 @@ class ServingEngine:
             lvl[rec.slots[0]] = rec.req.policy.level  # slots[0] == column
         tmr = any(r.req.policy.level >= 3 for r in recs)
         events, struck = (
-            np.asarray(jax.device_get(x))
-            for x in self._get_detect(tmr)(states[self.adapter.cell], lvl)
+            np.asarray(x)
+            for x in self._sync(
+                "sync.fingerprints",
+                self._get_detect(tmr)(states[self.adapter.cell], lvl),
+            )
         )
         fps = rfps = replay = None  # lazy: one replay serves every event
         for rec in recs:
@@ -947,7 +1011,7 @@ class ServingEngine:
                 # same pair precedence as the temporal [(0,1),(0,2),(1,2)]
                 bad = int(struck[col])
                 good = 0 if bad != 0 else 1
-                dmg = self._ops.damage(states, s[good], s[bad])
+                dmg = self._damage(self._ops.damage, states, s[good], s[bad])
                 if tr is not None:
                     tr.instant(
                         "strike_attributed",
@@ -973,15 +1037,23 @@ class ServingEngine:
                 else:
                     replay, _ = self.exe.pure_step(self._tick_input, t)
                 fps = np.asarray(
-                    jax.device_get(self._ops.fingerprints(states[self.adapter.cell]))
+                    self._sync(
+                        "sync.fingerprints",
+                        self._ops.fingerprints(states[self.adapter.cell]),
+                    )
                 )
                 rfps = np.asarray(
-                    jax.device_get(self._ops.fingerprints(replay[self.adapter.cell]))
+                    self._sync(
+                        "sync.fingerprints",
+                        self._ops.fingerprints(replay[self.adapter.cell]),
+                    )
                 )
             bad = [
                 i for i, sl in enumerate(s) if not np.array_equal(fps[sl], rfps[sl])
             ]
-            dmg = sum(self._ops.damage_vs(states, replay, s[b]) for b in bad)
+            dmg = sum(
+                self._damage(self._ops.damage_vs, states, replay, s[b]) for b in bad
+            )
             if tr is not None:
                 tr.instant(
                     "strike_attributed",
@@ -991,8 +1063,7 @@ class ServingEngine:
                     pods=list(bad),
                     damage_elems=float(dmg),
                 )
-            for sl in s:
-                states = self._ops.adopt(states, replay, sl)
+            states = self._adopt(states, replay, s)
             self._attribute(rec, t, bad, level, dmg)
             if tr is not None:
                 tr.instant("strike_repaired", rec.id, step=t, repair="dmr_replay")
@@ -1067,6 +1138,9 @@ class ServingEngine:
             self._m_terminal[status].inc()
         self._h_latency.observe(rec.finished_at - rec.submitted_at)
         if self.tracer is not None:
+            if rec.trace_queue is not None:  # left the queue unserved
+                rec.trace_queue.stop()
+                rec.trace_queue = None
             if rec.trace_walk_open:  # evicted mid-walk: close inner span
                 self.tracer.end(rec.id, "prefill_walk")
                 rec.trace_walk_open = False

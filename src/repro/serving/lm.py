@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.jit import named
 from repro.distributed.sharding import LOCAL, ShardCtx
 from repro.models.config import ModelConfig
 from repro.models.lm_cells import (
@@ -120,7 +121,7 @@ def lm_engine_parts(cfg: ModelConfig, scfg: ServeConfig, ctx: ShardCtx = LOCAL):
             budget=budget if spec else None,
         )
 
-    jit_prefill = jax.jit(_prefill_impl)
+    jit_prefill = jax.jit(named(_prefill_impl, "prefill"))
     buckets_used: set = set()
 
     tail_dims = (cfg.n_codebooks,) if cfg.n_codebooks > 1 else ()
@@ -263,10 +264,11 @@ def lm_engine_parts(cfg: ModelConfig, scfg: ServeConfig, ctx: ShardCtx = LOCAL):
         return slot_decoder_init(cfg, 1, scfg.max_len, dcfg, spec_len)
 
     def attach_tracer(tracer) -> None:
-        # the paged pre-tick hook emits its own page_fault instants;
-        # dense engines have no adapter-side emitters (no-op)
+        # the paged pre-tick hook and join emit their own events; dense
+        # engines have no adapter-side emitters (no-op)
         if pre_tick is not None:
             pre_tick.tracer = tracer
+            surgery.join.tracer = tracer
 
     adapter = SlotAdapter(
         cell="decoder",

@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.jit import forwarding_jit
+from repro.core.jit import forwarding_jit, named
 from repro.core.redundancy import bit_mismatch_elems
 
 from .slots import SlotSurgery, _bcast, read_slot, slot_fingerprints
@@ -419,12 +419,17 @@ def paged_surgery(
 
     # forwarding: the cells a slot op leaves alone (the weights) come back
     # as the same buffers, not copies
-    jit_install = forwarding_jit(_install)
-    jit_scrub = forwarding_jit(_scrub)
-    jit_copy = forwarding_jit(_copy)
-    jit_adopt = forwarding_jit(_adopt)
-    jit_fps = jax.jit(lambda dec: slot_fingerprints(
-        dec, vaxes, n=dec["pages"].shape[0], read=paged_read_slot))
+    jit_install = forwarding_jit(_install, name="paged_install")
+    jit_scrub = forwarding_jit(_scrub, name="paged_scrub")
+    jit_copy = forwarding_jit(_copy, name="paged_copy")
+    jit_adopt = forwarding_jit(_adopt, name="paged_adopt")
+
+    def _fps_impl(dec):
+        return slot_fingerprints(
+            dec, vaxes, n=dec["pages"].shape[0], read=paged_read_slot
+        )
+
+    jit_fps = jax.jit(named(_fps_impl, "paged_fingerprints"))
 
     def _damage_impl(st, a, b):
         return bit_mismatch_elems(
@@ -438,8 +443,8 @@ def paged_surgery(
             paged_read_slot(other[cell], slot, vaxes),
         )
 
-    jit_damage = jax.jit(_damage_impl)
-    jit_damage_vs = jax.jit(_damage_vs_impl)
+    jit_damage = jax.jit(named(_damage_impl, "paged_damage"))
+    jit_damage_vs = jax.jit(named(_damage_vs_impl, "paged_damage_vs"))
 
     def join(st, ss, slot, req=None):
         if req is None:
@@ -448,7 +453,11 @@ def paged_surgery(
                 "(page reservation sizing)"
             )
         table.assign(slot, reserve_fn(req))
-        pos0 = int(jax.device_get(ss["cache"]["pos"][0]))
+        if join.tracer is not None:
+            with join.tracer.span("sync.join_pos", "engine"):
+                pos0 = int(jax.device_get(ss["cache"]["pos"][0]))
+        else:
+            pos0 = int(jax.device_get(ss["cache"]["pos"][0]))
         table.grow_to(slot, pos0)  # install pages: admission, not faults
         rows = jnp.asarray(table.row_array(slot))
         return jit_install(st, ss, jnp.int32(slot), rows)
@@ -475,6 +484,8 @@ def paged_surgery(
     def _damage_vs_host(st, other, slot):
         return float(jax.device_get(jit_damage_vs(st, other, jnp.int32(slot))))
 
+    #: set by SlotAdapter.attach_tracer, as ``pre_tick.tracer`` is
+    join.tracer = None
     return SlotSurgery(
         join=join,
         scrub=scrub,
@@ -533,14 +544,24 @@ def make_pre_tick(
         }
         return {**st, cell: new}
 
-    jit_grow = forwarding_jit(grow)
+    jit_grow = forwarding_jit(grow, name="paged_grow")
 
     def pre_tick(states):
+        if pre_tick.tracer is not None:
+            with pre_tick.tracer.span("page_grow", "engine"):
+                return grow_pages(states, pre_tick.tracer)
+        return grow_pages(states, None)
+
+    def grow_pages(states, tracer):
         dec = states[cell]
         leaves = [dec["active"], dec["cache"]["pos"], dec["p_head"], dec["p_len"]]
         if draft_len > 0:
             leaves += [dec["spec_k"], dec["budget"], dec["n_decoded"]]
-        host = [np.asarray(x) for x in jax.device_get(leaves)]
+        if tracer is not None:
+            with tracer.span("sync.page_state", "engine"):
+                host = [np.asarray(x) for x in jax.device_get(leaves)]
+        else:
+            host = [np.asarray(x) for x in jax.device_get(leaves)]
         act, pos, p_head, p_len = host[:4]
         rows = np.full((batch, table.pages_per_slot), -1, np.int32)
         grew = np.zeros((batch,), bool)
@@ -569,10 +590,10 @@ def make_pre_tick(
                 clean.extend(new)
                 rows[s] = table.row_array(s)
                 grew[s] = True
-                if pre_tick.tracer is not None:
+                if tracer is not None:
                     # one instant per faulting slot: which pool pages
                     # the demand-map just pulled in and for what position
-                    pre_tick.tracer.instant(
+                    tracer.instant(
                         "page_fault",
                         "engine",
                         slot=s,

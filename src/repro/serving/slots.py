@@ -25,7 +25,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.jit import forwarding_jit
+from repro.core.jit import forwarding_jit, named
 from repro.core.redundancy import bit_mismatch_elems, fingerprint
 
 Pytree = Any
@@ -164,18 +164,20 @@ def default_surgery(
     # forwarding: the cells a slot op leaves alone (the weights) come back
     # as the same buffers, not copies
     _join = forwarding_jit(
-        lambda st, ss, slot: {**st, cell: join_slot(st[cell], ss, slot, axes)}
+        lambda st, ss, slot: {**st, cell: join_slot(st[cell], ss, slot, axes)},
+        name="slot_join",
     )
     _copy = forwarding_jit(
-        lambda st, src, dst: {**st, cell: copy_slot(st[cell], src, dst, axes)}
+        lambda st, src, dst: {**st, cell: copy_slot(st[cell], src, dst, axes)},
+        name="slot_copy",
     )
 
     def _adopt_impl(st, other, slot):
         taken = read_slot(other[cell], slot, axes)
         return {**st, cell: join_slot(st[cell], taken, slot, axes)}
 
-    _adopt = forwarding_jit(_adopt_impl)
-    _fps = jax.jit(lambda dec: slot_fingerprints(dec, axes))
+    _adopt = forwarding_jit(_adopt_impl, name="slot_adopt")
+    _fps = jax.jit(named(lambda dec: slot_fingerprints(dec, axes), "slot_fingerprints"))
 
     # real damage accounting: mismatched ELEMENTS between two replica
     # slots (same semantics as temporal lockstep's bitwise compare), not
@@ -190,8 +192,8 @@ def default_surgery(
             read_slot(st[cell], slot, axes), read_slot(other[cell], slot, axes)
         )
 
-    _damage = jax.jit(_damage_impl)
-    _damage_vs = jax.jit(_damage_vs_impl)
+    _damage = jax.jit(named(_damage_impl, "slot_damage"))
+    _damage_vs = jax.jit(named(_damage_vs_impl, "slot_damage_vs"))
 
     def _damage_host(st, a, b):
         return float(jax.device_get(_damage(st, jnp.int32(a), jnp.int32(b))))

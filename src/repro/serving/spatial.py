@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from jax import shard_map
+from repro.core.jit import named
 from repro.distributed.collectives import psum_delta
 
 from .slots import SlotSurgery, slot_fingerprints
@@ -94,7 +95,7 @@ def make_detect(mesh, axes, *, pod_axis: str = "pod", tmr: bool):
         out_specs=(P(), P()),
         check_vma=False,
     )
-    return jax.jit(mapped)
+    return jax.jit(named(mapped, "spatial_detect"))
 
 
 def detect_wire_bytes(n_pods: int, spp: int, tmr: bool) -> int:

@@ -125,7 +125,6 @@ def test_tracer_export_passes_schema_checker():
     fid = tr.flow_id()
     tr.flow_start(fid, "r0", "strike")
     tr.flow_end(fid, "r0", "strike")
-    tr.counter("depth", "engine", queued=2)
     tr.end("r0", "request")
     assert validate_events(tr.events()) == []
 
