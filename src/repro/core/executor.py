@@ -57,7 +57,7 @@ import collections
 import dataclasses
 import time
 import warnings
-from typing import Any, Callable, Iterator, Mapping, Optional
+from typing import Any, Callable, Collection, Iterator, Mapping, Optional
 
 import jax
 import jax.numpy as jnp
@@ -172,6 +172,10 @@ class Executor:
     """
 
     name: str = "base"
+    #: ``step(donate=...)`` really hands the named cells' buffers to the
+    #: compiled step, which writes its result over them; back-ends
+    #: without it ignore ``donate`` and keep their input
+    step_donates: bool = False
 
     def __init__(
         self,
@@ -242,7 +246,11 @@ class Executor:
         *,
         step_idx: Optional[int] = None,
         fault: Optional[FaultSpec] = None,
+        donate: Collection[str] = (),
     ) -> tuple[dict, dict]:
+        """One step window.  ``donate`` names the cells whose input
+        buffers the step may consume (see ``step_donates``): the caller
+        holds no other use for them, and they are deleted."""
         raise NotImplementedError
 
     def pure_step(
@@ -375,6 +383,7 @@ class Executor:
         start_step: Optional[int] = None,
         faults=None,
         swap: Optional[Callable[[int, dict], Optional[dict]]] = None,
+        donate: Optional[Callable[[], Collection[str]]] = None,
     ) -> Iterator[tuple[dict, dict]]:
         """Generator of per-step ``(states, reports)`` — the serving loop.
         Each tick advances ``step_stride`` transitions (1 unless the
@@ -386,7 +395,10 @@ class Executor:
         the resident states for that tick and onward.  This is how the
         continuous batcher joins/leaves requests in the decoder cell's
         batch between ticks without tearing the stream down.  Checkpoints
-        (``checkpoint_cb``) snapshot the post-swap pre-step buffer."""
+        (``checkpoint_cb``) snapshot the post-swap pre-step buffer.
+
+        ``donate``, called after ``swap`` on every tick, names the cells
+        that tick's step may consume (``step(donate=...)``)."""
         stride = self.step_stride
         if n_steps is not None and n_steps % stride != 0:
             raise ValueError("n_steps must be a multiple of compare_every")
@@ -400,11 +412,14 @@ class Executor:
                     states = swapped
             self._maybe_checkpoint(t, states)
             fault = _fault_in_window(flist, t, stride)
+            names = donate() if donate is not None else ()
             if self.span_hook is not None:
                 with self.span_hook("step"):
-                    states, rep = self.step(states, step_idx=t, fault=fault)
+                    states, rep = self.step(
+                        states, step_idx=t, fault=fault, donate=names)
             else:
-                states, rep = self.step(states, step_idx=t, fault=fault)
+                states, rep = self.step(
+                    states, step_idx=t, fault=fault, donate=names)
             yield states, rep
             t += stride
 
@@ -528,6 +543,8 @@ class LockstepExecutor(Executor):
     others), so ``step``/``run`` granularity is k transitions.
     """
 
+    step_donates = True
+
     def _compile_step(self, *, with_compare: bool):
         """Step-function factory hook.  Subclasses (the Pallas-fused
         ``lockstep_pallas`` back-end) swap the per-cell transition/compare
@@ -557,11 +574,14 @@ class LockstepExecutor(Executor):
         self._jit_plain_window = None   # lazy: pure_step(compare=False)
         self._run_cache: dict = {}
 
-    def step(self, states, *, step_idx=None, fault=None):
+    def step(self, states, *, step_idx=None, fault=None, donate=()):
         t = self._t if step_idx is None else int(step_idx)
         fault = fault if fault is not None else FaultSpec.none()
+        mask = (({name: name in donate for name in states}, False, False)
+                if donate else None)
         with self._mesh_ctx():
-            states, reports = self._jit_step(states, jnp.int32(t), fault)
+            states, reports = self._jit_step(
+                states, jnp.int32(t), fault, donate=mask)
         # the replica compare runs on the window's last sub-step — attribute
         # events there, matching run()'s per-step ledger entries
         self._ledger_update(t + self.compare_every - 1, reports)
@@ -799,7 +819,7 @@ class HostExecutor(Executor):
         with self._mesh_ctx():
             return self._step(states, jnp.int32(int(step_idx)), fault)
 
-    def step(self, states, *, step_idx=None, fault=None):
+    def step(self, states, *, step_idx=None, fault=None, donate=()):
         t = self._t if step_idx is None else int(step_idx)
         prev = states  # immutable previous buffer (double buffering)
         fault = fault if fault is not None else FaultSpec.none()
@@ -896,7 +916,7 @@ class WavefrontExecutor(Executor):
 
         return jax.jit(ustep) if jit else ustep
 
-    def step(self, states, *, step_idx=None, fault=None):
+    def step(self, states, *, step_idx=None, fault=None, donate=()):
         """One globally synchronized transition (all units advance once).
         Read-prev semantics make unit order irrelevant within a step."""
         t = self._t if step_idx is None else int(step_idx)

@@ -11,6 +11,11 @@ index map reads the page id, so unmapped pages are never fetched twice),
 and the final step runs exactly the dense ``decode_attention`` /
 absorbed-MLA math over the gathered scratch.
 
+The pool may hold every layer's pages, stacked on a leading layer axis;
+a second scalar-prefetch operand, the layer index, then picks the
+layer's pages in the index map, so the decode step hands the kernel the
+stack it updates in place and no per-layer slice of it is ever made.
+
 Bitwise parity with the dense path is load-bearing (the serving engine's
 paged-vs-dense token parity gate): the finalize step performs the SAME
 ops in the SAME f32 shapes and lane order as ``layers.decode_attention``
@@ -47,6 +52,7 @@ def _resolve_interpret(interpret: bool | None) -> bool:
 # --------------------------------------------------------------------------
 def _gqa_kernel(
     pm_ref,  # (B, P) int32 scalar-prefetch: page table (-1 = unmapped)
+    layer_ref,  # (1,) int32 scalar-prefetch: layer of the stacked pool
     q_ref,  # (1, Hq, Dk) block
     k_ref,  # (1, Hkv, ps, Dk) block: the page selected by the index map
     v_ref,  # (1, Hkv, ps, Dk) block
@@ -98,6 +104,16 @@ def _valid_lanes(pages: jax.Array, pos: jax.Array, ps: int) -> jax.Array:
     return valid.astype(jnp.int32)[:, None]
 
 
+def _stacked(pools: tuple, layer, ndim: int) -> tuple:
+    """The pools with a leading layer axis, and the layer index as the
+    (1,) int32 scalar-prefetch operand.  A single layer's pool (``layer``
+    None) gains a size-one axis, a free reshape."""
+    if layer is None:
+        pools, layer = tuple(p[None] for p in pools), 0
+    assert all(p.ndim == ndim for p in pools), [p.shape for p in pools]
+    return pools, jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 def _vmem_limit(scratch_bytes: int) -> int:
     """Scoped-VMEM budget: the gathered scratch plus the f32 copies the
     finalize makes of it, with headroom, within the v5e's 128 MiB."""
@@ -106,11 +122,12 @@ def _vmem_limit(scratch_bytes: int) -> int:
 
 def paged_gqa_attention(
     q: jax.Array,  # (B, Hq, Dk)
-    k_pool: jax.Array,  # (N, Hkv, ps, Dk) shared page pool
-    v_pool: jax.Array,  # (N, Hkv, ps, Dk)
+    k_pool: jax.Array,  # (N, Hkv, ps, Dk) shared page pool, or (L, N, ...)
+    v_pool: jax.Array,  # (N, Hkv, ps, Dk), or (L, N, Hkv, ps, Dk)
     pages: jax.Array,  # (B, P) int32 per-slot page table, -1 = unmapped
     pos: jax.Array,  # (B,) int32 current query position
     *,
+    layer: jax.Array | int | None = None,
     scale: float | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
@@ -118,9 +135,12 @@ def paged_gqa_attention(
 
     Bit-identical to ``layers.decode_attention`` over the equivalent
     dense cache (pages gathered in logical order, unmapped pages = zero
-    lanes masked invalid).  Returns (B, Hq, Dk) in q.dtype."""
+    lanes masked invalid).  With ``layer`` the pools are the stack of
+    every layer's pool and the kernel reads layer ``layer``'s pages.
+    Returns (B, Hq, Dk) in q.dtype."""
+    (k_pool, v_pool), layer = _stacked((k_pool, v_pool), layer, 5)
     B, Hq, Dk = q.shape
-    _, Hkv, ps, _ = k_pool.shape
+    _, _, Hkv, ps, _ = k_pool.shape
     P = pages.shape[1]
     assert Hq % Hkv == 0, (Hq, Hkv)
     G = Hq // Hkv
@@ -131,18 +151,19 @@ def paged_gqa_attention(
         _gqa_kernel, scale=scale, ps=ps, n_pages_per_slot=P, hkv=Hkv, group=G
     )
     page = pl.BlockSpec(
-        (1, Hkv, ps, Dk), lambda b, i, pm: (jnp.maximum(pm[b, i], 0), 0, 0, 0)
+        (pl.squeezed, 1, Hkv, ps, Dk),
+        lambda b, i, pm, li: (li[0], jnp.maximum(pm[b, i], 0), 0, 0, 0),
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, P),
         in_specs=[
-            pl.BlockSpec((1, Hq, Dk), lambda b, i, pm: (b, 0, 0)),
+            pl.BlockSpec((1, Hq, Dk), lambda b, i, pm, li: (b, 0, 0)),
             page,
             page,
-            pl.BlockSpec((1, 1, seq), lambda b, i, pm: (b, 0, 0)),
+            pl.BlockSpec((1, 1, seq), lambda b, i, pm, li: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, Hq, Dk), lambda b, i, pm: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hq, Dk), lambda b, i, pm, li: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((Hkv, seq, Dk), k_pool.dtype),
             pltpu.VMEM((Hkv, seq, Dk), v_pool.dtype),
@@ -159,7 +180,14 @@ def paged_gqa_attention(
         ),
         interpret=_resolve_interpret(interpret),
         name="paged_gqa_attention",
-    )(pages.astype(jnp.int32), q, k_pool, v_pool, _valid_lanes(pages, pos, ps))
+    )(
+        pages.astype(jnp.int32),
+        layer,
+        q,
+        k_pool,
+        v_pool,
+        _valid_lanes(pages, pos, ps),
+    )
 
 
 # --------------------------------------------------------------------------
@@ -167,6 +195,7 @@ def paged_gqa_attention(
 # --------------------------------------------------------------------------
 def _mla_kernel(
     pm_ref,  # (B, P) int32
+    layer_ref,  # (1,) int32: layer of the stacked pool
     ql_ref,  # (1, h, lora) block: latent-absorbed query
     qr_ref,  # (1, h, rope) block: rope query
     ckv_ref,  # (1, ps, lora) block: selected latent page
@@ -211,39 +240,44 @@ def _mla_kernel(
 def paged_mla_attention(
     q_lat: jax.Array,  # (B, h, lora) latent-absorbed query
     q_rope: jax.Array,  # (B, h, rope)
-    ckv_pool: jax.Array,  # (N, ps, lora)
-    krope_pool: jax.Array,  # (N, ps, rope)
+    ckv_pool: jax.Array,  # (N, ps, lora), or (L, N, ps, lora)
+    krope_pool: jax.Array,  # (N, ps, rope), or (L, N, ps, rope)
     pages: jax.Array,  # (B, P) int32
     pos: jax.Array,  # (B,) int32
     *,
     scale: float,
+    layer: jax.Array | int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Absorbed-MLA single-query attention through a page table.  Returns
     the f32 latent context (B, h, lora) — bit-identical to the dense
-    absorbed decode's ``einsum("bhst,btl->bshl", softmax(s), ckv)``."""
+    absorbed decode's ``einsum("bhst,btl->bshl", softmax(s), ckv)``.
+    ``layer`` as in ``paged_gqa_attention``."""
+    (ckv_pool, krope_pool), layer = _stacked((ckv_pool, krope_pool), layer, 4)
     B, h, lora = q_lat.shape
-    _, ps, _ = ckv_pool.shape
+    _, _, ps, _ = ckv_pool.shape
     P = pages.shape[1]
     rope = q_rope.shape[-1]
     seq = P * ps
 
     kernel = functools.partial(_mla_kernel, scale=scale, ps=ps, n_pages_per_slot=P)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, P),
         in_specs=[
-            pl.BlockSpec((1, h, lora), lambda b, i, pm: (b, 0, 0)),
-            pl.BlockSpec((1, h, rope), lambda b, i, pm: (b, 0, 0)),
+            pl.BlockSpec((1, h, lora), lambda b, i, pm, li: (b, 0, 0)),
+            pl.BlockSpec((1, h, rope), lambda b, i, pm, li: (b, 0, 0)),
             pl.BlockSpec(
-                (1, ps, lora), lambda b, i, pm: (jnp.maximum(pm[b, i], 0), 0, 0)
+                (pl.squeezed, 1, ps, lora),
+                lambda b, i, pm, li: (li[0], jnp.maximum(pm[b, i], 0), 0, 0),
             ),
             pl.BlockSpec(
-                (1, ps, rope), lambda b, i, pm: (jnp.maximum(pm[b, i], 0), 0, 0)
+                (pl.squeezed, 1, ps, rope),
+                lambda b, i, pm, li: (li[0], jnp.maximum(pm[b, i], 0), 0, 0),
             ),
-            pl.BlockSpec((1, 1, seq), lambda b, i, pm: (b, 0, 0)),
+            pl.BlockSpec((1, 1, seq), lambda b, i, pm, li: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, h, lora), lambda b, i, pm: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, lora), lambda b, i, pm, li: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((seq, lora), ckv_pool.dtype),
             pltpu.VMEM((seq, rope), krope_pool.dtype),
@@ -262,6 +296,7 @@ def paged_mla_attention(
         name="paged_mla_attention",
     )(
         pages.astype(jnp.int32),
+        layer,
         q_lat,
         q_rope,
         ckv_pool,

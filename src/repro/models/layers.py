@@ -181,6 +181,24 @@ def decode_attention(
     return out.reshape(B, Hq, 1, Dv).astype(q.dtype)
 
 
+def page_write(pool: jax.Array, layer, row: jax.Array, lane: jax.Array,
+               ok: jax.Array, new: jax.Array) -> jax.Array:
+    """``pool.at[layer, row, :, lane].set(new)`` for each slot where
+    ``ok``, in a stack of page pools: (L, N, H, ps, d) with ``new`` (B, H,
+    d), or (L, N, ps, d) with ``new`` (B, d).  Written as a scatter of
+    whole ``d``-rows into the pool seen as (rows, d), a reshape that moves
+    no byte: the pool keeps the layout the paged kernels read, so XLA
+    updates it in place instead of copying it into a layout the scatter
+    prefers and back.  Rows where not ``ok`` are dropped."""
+    n, ps, d = pool.shape[1], pool.shape[-2], pool.shape[-1]
+    heads = pool.shape[2] if pool.ndim == 5 else 1
+    at = ((layer * n + row)[:, None] * heads + jnp.arange(heads)) * ps
+    at = jnp.where(ok[:, None], at + lane[:, None], pool.size // d)
+    rows = pool.reshape(-1, d).at[at.reshape(-1)].set(
+        new.reshape(-1, d).astype(pool.dtype), mode="drop")
+    return rows.reshape(pool.shape)
+
+
 # --------------------------------------------------------------------------
 # GQA attention block (covers MHA / GQA / MQA / SWA / M-RoPE)
 # --------------------------------------------------------------------------
@@ -233,6 +251,7 @@ def gqa_attention(
     ctx=None,                             # ShardCtx for decode_shardmap
     active: Optional[jax.Array] = None,   # (B,) serving slot mask (decode)
     pages: Optional[jax.Array] = None,    # (B,P) page table -> paged decode
+    layer: Optional[jax.Array] = None,    # this layer in the stacked pool
 ) -> tuple[jax.Array, Optional[dict]]:
     B, S, d = x.shape
     dh = cfg.head_dim
@@ -262,28 +281,26 @@ def gqa_attention(
         pos = positions[0] if cfg.mrope_sections else positions  # (B,S)
         pos = pos[:, 0]                                          # (B,)
         if pages is not None:
-            # paged decode: cache is the shared page pool (N,Hkv,ps,dh);
-            # the write lands at (row, lane) through the page table, and
-            # attention reads every mapped page via the fused kernel.
+            # paged decode: cache is every layer's shared page pool,
+            # stacked (L,N,Hkv,ps,dh); the write lands at (layer, row,
+            # lane) through the page table, in place, and attention
+            # reads this layer's mapped pages out of the stack via the
+            # fused kernel.
             assert not cfg.window, "paged decode excludes windowed archs"
             from repro.kernels.paged_decode import paged_gqa_attention
 
-            N, _, psz, _ = cache["k"].shape
+            psz = cache["k"].shape[3]
             lane = pos % psz
             row = jnp.take_along_axis(pages, (pos // psz)[:, None], 1)[:, 0]
             ok = row >= 0
             if active is not None:
                 ok = ok & active
-            # OOB rows are DROPPED by the scatter: inactive slots and
-            # unmapped pages write nothing (page rows are per-slot
-            # disjoint, so no cross-slot collisions either way)
-            row_safe = jnp.where(ok, row, N)
-            k_pool = cache["k"].at[row_safe, :, lane].set(
-                k[:, :, 0].astype(cache["k"].dtype))
-            v_pool = cache["v"].at[row_safe, :, lane].set(
-                v[:, :, 0].astype(cache["v"].dtype))
+            # inactive slots and unmapped pages write nothing (page rows
+            # are per-slot disjoint, so no cross-slot collisions either way)
+            k_pool = page_write(cache["k"], layer, row, lane, ok, k[:, :, 0])
+            v_pool = page_write(cache["v"], layer, row, lane, ok, v[:, :, 0])
             out = paged_gqa_attention(q[:, :, 0], k_pool, v_pool,
-                                      pages, pos)
+                                      pages, pos, layer=layer)
             out = out[:, None].reshape(B, S, cfg.n_heads * dh)
             return out @ p["wo"], {"k": k_pool, "v": v_pool}
         if (ctx is not None and getattr(ctx, "decode_shardmap", False)
@@ -374,6 +391,7 @@ def mla_attention(
     ctx=None,                             # ShardCtx for decode_shardmap
     active: Optional[jax.Array] = None,   # (B,) serving slot mask (decode)
     pages: Optional[jax.Array] = None,    # (B,P) page table -> paged decode
+    layer: Optional[jax.Array] = None,    # this layer in the stacked pool
 ) -> tuple[jax.Array, Optional[dict]]:
     m = cfg.mla or MLAConfig()
     B, S, d = x.shape
@@ -418,21 +436,20 @@ def mla_attention(
     if pages is not None:
         from repro.kernels.paged_decode import paged_mla_attention
 
-        N, psz, _ = cache["ckv"].shape
+        # cache: every layer's latent pool, stacked (L,N,ps,lora|rope)
+        psz = cache["ckv"].shape[2]
         lane = pos % psz
         row = jnp.take_along_axis(pages, (pos // psz)[:, None], 1)[:, 0]
         ok = row >= 0
         if active is not None:
             ok = ok & active
-        row_safe = jnp.where(ok, row, N)  # OOB scatter -> dropped
-        ckv_pool = cache["ckv"].at[row_safe, lane].set(
-            ckv[:, 0].astype(cache["ckv"].dtype))
-        krope_pool = cache["krope"].at[row_safe, lane].set(
-            k_rope[:, 0].astype(cache["krope"].dtype))
+        ckv_pool = page_write(cache["ckv"], layer, row, lane, ok, ckv[:, 0])
+        krope_pool = page_write(cache["krope"], layer, row, lane, ok,
+                                k_rope[:, 0])
         q_lat = jnp.einsum("bshn,lhn->bshl", q_nope, w_uk)  # (B,1,h,lora)
         ctx_lat = paged_mla_attention(
             q_lat[:, 0], q_rope[:, 0], ckv_pool, krope_pool, pages, pos,
-            scale=scale,
+            scale=scale, layer=layer,
         )                                                   # (B,h,lora) f32
         out = jnp.einsum("bshl,lhv->bshv", ctx_lat[:, None].astype(x.dtype),
                          w_uv)

@@ -186,10 +186,13 @@ def _shared_attn_apply(shared: Params, xin: jax.Array, cfg: ModelConfig,
 
 
 def _attention(p, x, cfg: ModelConfig, ctx: ShardCtx, positions, cache,
-               fill_cache, active=None, prompt_len=None, pages=None):
+               fill_cache, active=None, prompt_len=None, pages=None,
+               layer=None):
     """Returns (out, cache_out).  cache_out is the updated cache (decode),
     the filled cache (fill_cache), or None.  ``active`` is the serving
     batcher's per-slot mask, threaded into the decode cache update.
+    With ``pages`` the cache is every layer's page pool, stacked, and
+    ``layer`` this layer's index into it.
     ``prompt_len`` (scalar, may be traced) masks the *fill* path for
     bucket-padded prefill: cache entries at positions >= prompt_len are
     scrubbed (slot_pos=-1, zero K/V) so the filled cache is
@@ -198,7 +201,7 @@ def _attention(p, x, cfg: ModelConfig, ctx: ShardCtx, positions, cache,
     fn = L.mla_attention if cfg.attn_type == "mla" else L.gqa_attention
     if cache is not None:
         return fn(p, x, cfg, positions=positions, cache=cache, ctx=ctx,
-                  active=active, pages=pages)
+                  active=active, pages=pages, layer=layer)
     out, _ = fn(p, x, cfg, positions=positions, cache=None,
                 block_k=ctx.block_k)
     if not fill_cache:
@@ -257,7 +260,7 @@ def _attention(p, x, cfg: ModelConfig, ctx: ShardCtx, positions, cache,
 def _layer_apply(p: Params, h: jax.Array, cfg: ModelConfig, kind: str,
                  ctx: ShardCtx, positions, cache, fill_cache,
                  shared: Optional[Params], e0: Optional[jax.Array],
-                 active=None, prompt_len=None, pages=None):
+                 active=None, prompt_len=None, pages=None, layer=None):
     """One scan step.  Returns (h, cache_out, aux)."""
     aux = jnp.float32(0)
     if kind == "mamba":
@@ -295,7 +298,7 @@ def _layer_apply(p: Params, h: jax.Array, cfg: ModelConfig, kind: str,
     # attn_mlp / attn_moe
     a, cout = _attention(p["attn"], L.rmsnorm(h, p["ln1"], cfg.rms_eps),
                          cfg, ctx, positions, cache, fill_cache, active,
-                         prompt_len, pages)
+                         prompt_len, pages, layer)
     # pin the TP boundary on the bf16 block output: without the constraint
     # the partitioner is free to place the model-axis all-reduce after the
     # f32 upcast of the next rmsnorm, doubling its wire bytes (§Perf)
@@ -519,6 +522,13 @@ def init_paged_cache(cfg: ModelConfig, batch: int, n_pages: int,
     return {"segments": out, "pos": jnp.zeros((batch,), jnp.int32)}
 
 
+def _is_page_pool(seg_cache) -> bool:
+    """A segment cache that is a stack of per-layer page pools (GQA
+    ``k``/``v`` or MLA ``ckv``/``krope``, no per-slot ``slot_pos``)."""
+    return isinstance(seg_cache, dict) and set(seg_cache) in (
+        {"k", "v"}, {"ckv", "krope"})
+
+
 def decode_step(
     cfg: ModelConfig, params: Params, cache: dict, tokens: jax.Array,
     *, ctx: ShardCtx = LOCAL, active: Optional[jax.Array] = None,
@@ -533,6 +543,12 @@ def decode_step(
     attention cache-update paths (local scatter and the shard_map decode
     of ``distributed/decode.py``); callers that hold whole-state slots
     (the serving decoder cell) additionally gate their state writeback.
+
+    A paged segment's cache (a page pool per layer, stacked) rides in the
+    layer loop's carry: each layer writes its token into the stack in
+    place and its attention kernel reads the stack by layer index, so the
+    step never copies a layer's pool out of the stack or back into it.
+    Dense caches and recurrent state are scanned over, layer by layer.
     """
     B = tokens.shape[0]
     pos = cache["pos"]                       # (B,)
@@ -546,11 +562,33 @@ def decode_step(
     new_segs = []
     for seg, sp, sc in zip(segment_plan(cfg), params["segments"],
                            cache["segments"]):
+        if _is_page_pool(sc):
+            def body(carry, xs):
+                h, pool = carry
+                lp, i = xs
+                h, pool, _ = _layer_apply(
+                    lp, h, cfg, seg.kind, ctx, positions, pool, False,
+                    shared, e0, active, None, pages, i,
+                )
+                return (h, pool), None
+
+            layers = jnp.arange(seg.count, dtype=jnp.int32)
+            if ctx.unroll:
+                carry = (h, sc)
+                for i in range(seg.count):
+                    carry, _ = body(carry, jax.tree.map(
+                        lambda x, i=i: x[i], (sp, layers)))
+                h, new_c = carry
+            else:
+                (h, new_c), _ = jax.lax.scan(body, (h, sc), (sp, layers))
+            new_segs.append(new_c)
+            continue
+
         def body(h, xs):
             lp, lc = xs
             h, cout, _ = _layer_apply(
                 lp, h, cfg, seg.kind, ctx, positions, lc, False, shared, e0,
-                active, None, pages,
+                active,
             )
             return h, cout
 

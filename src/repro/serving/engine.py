@@ -389,11 +389,22 @@ class ServingEngine:
         self._override: Optional[dict] = None
         self._tick_input: Optional[dict] = None
         self._tick_step: int = 0
+        #: the step may write the decoder cell's new state over its input
+        #: buffers on a tick whose pre-step state nothing reads again: no
+        #: checkpoint callback, and (decided per tick in ``_swap``) no
+        #: replicated request resident, so no §IV replay
+        self._may_donate = self.exe.step_donates and self.exe.checkpoint_cb is None
+        #: the cells the current tick's step consumes
+        self._donate: tuple[str, ...] = ()
         #: counters live in the registry (typed instruments with
         #: Prometheus/JSON exposition replace the old ad-hoc ints);
         #: ``metrics()`` reads them back under the historical key names
         R = self.registry
         self._m_ticks = R.counter("serving_ticks_total", "engine ticks executed")
+        self._m_in_place = R.counter(
+            "serving_pool_in_place_ticks_total",
+            "ticks whose step wrote the decoder state over its input buffers",
+        )
         self._m_tokens = R.counter(
             "serving_tokens_emitted_total", "tokens emitted to requests"
         )
@@ -581,7 +592,9 @@ class ServingEngine:
             return 0
         ticks = 0
         tr = self.tracer
-        stream = self.exe.stream(self._states, swap=self._swap, faults=faults)
+        stream = self.exe.stream(
+            self._states, swap=self._swap, faults=faults, donate=lambda: self._donate
+        )
         try:
             while True:
                 tick_t0 = self.time_fn()
@@ -595,6 +608,8 @@ class ServingEngine:
                 self._states = states
                 self._override = states
                 self._m_ticks.inc()
+                if self._donate:
+                    self._m_in_place.inc()
                 self._h_tick.observe(self.time_fn() - tick_t0)
                 ticks += 1
                 if max_ticks is not None and ticks >= max_ticks:
@@ -609,7 +624,8 @@ class ServingEngine:
         """One tick inside a ``tick`` span, split into the swap and step
         (``dispatch_us``, which ends with the executor's blocking reports
         read), the rest of the device work (``device_us``) and the
-        harvest (``harvest_us``)."""
+        harvest (``harvest_us``); ``pool_in_place`` is 1 when the step
+        wrote the decoder state over its input buffers."""
         with tr.span("tick", "engine") as tick:
             states, _reports = next(stream)
             ts1 = tr.now_us()
@@ -623,6 +639,7 @@ class ServingEngine:
                 dispatch_us=ts1 - tick.ts,
                 device_us=ts2 - ts1,
                 harvest_us=tr.now_us() - ts2,
+                pool_in_place=int(bool(self._donate)),
             )
         return states
 
@@ -646,7 +663,12 @@ class ServingEngine:
             # paged demand growth runs BEFORE the replay snapshot, so a
             # §IV replay of this tick sees the same page tables
             states = self.adapter.pre_tick(states)
-        self._tick_input = states  # immutable prev buffer (§IV replays)
+        if self._may_donate and not self.slots.replicated:
+            self._donate = (self.adapter.cell,)
+            self._tick_input = None
+        else:
+            self._donate = ()
+            self._tick_input = states  # immutable prev buffer (§IV replays)
         self._tick_step = t
         return states
 
@@ -1212,6 +1234,7 @@ class ServingEngine:
             "pods": self.pods,
             "n_slots": self.adapter.n_slots,
             "ticks": int(self._m_ticks.value),
+            "pool_in_place_ticks": int(self._m_in_place.value),
             "queue_depth": self.queue.depth,
             "active_requests": running,
             "free_slots": self.slots.free,

@@ -275,6 +275,11 @@ class SlotManager:
     def active(self) -> int:
         return self.n_slots - len(self._free)
 
+    @property
+    def replicated(self) -> bool:
+        """Some resident request holds replica slots (more than one)."""
+        return any(len(got) > 1 for got in self._slots_of.values())
+
     def slots_of(self, rid: str) -> list[int]:
         return list(self._slots_of.get(rid, ()))
 

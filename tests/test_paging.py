@@ -205,6 +205,66 @@ def test_paged_mla_kernel_bitwise_matches_ref():
     assert jnp.array_equal(got, ref), "MLA kernel diverged from reference"
 
 
+@pytest.mark.parametrize("kind", ["gqa", "mla"])
+def test_paged_kernel_reads_stacked_pool_by_layer_bitwise(kind):
+    """Each kernel handed every layer's pool, stacked, and a layer index
+    returns bit for bit what it returns on that layer's pool alone."""
+    from repro.kernels.paged_decode import paged_gqa_attention, paged_mla_attention
+
+    rng = np.random.default_rng(4)
+    L, ps, N = 3, 8, 10
+
+    def arr(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    pages = jnp.array([[7, 2, 9, 0], [5, 3, -1, -1], [8, -1, -1, -1]], jnp.int32)
+    pos = jnp.array([ps * 4 - 1, ps + 3, 0], jnp.int32)
+    B = pages.shape[0]
+    if kind == "gqa":
+        q = arr(B, 4, 8)
+        pools = (arr(L, N, 2, ps, 8), arr(L, N, 2, ps, 8))
+
+        def call(k, v, **kw):
+            return paged_gqa_attention(q, k, v, pages, pos, **kw)
+    else:
+        q_lat, q_rope = arr(B, 4, 16), arr(B, 4, 8)
+        pools = (arr(L, N, ps, 16), arr(L, N, ps, 8))
+
+        def call(ckv, kr, **kw):
+            return paged_mla_attention(q_lat, q_rope, ckv, kr, pages, pos, scale=0.2, **kw)
+
+    outs = []
+    for layer in range(L):
+        got = call(*pools, layer=jnp.int32(layer))
+        assert jnp.array_equal(got, call(*(p[layer] for p in pools))), layer
+        outs.append(got)
+    assert not jnp.array_equal(outs[0], outs[1])  # the index picks the layer
+
+
+@pytest.mark.parametrize("heads", [2, 0], ids=["gqa", "mla"])
+def test_page_write_sets_each_slot_token_and_drops_the_rest(heads):
+    """``page_write`` into a stack of pools equals one indexed ``set`` per
+    slot at (layer, row, lane); a slot that is not ``ok`` writes nothing,
+    though its row is a real page."""
+    from repro.models.layers import page_write
+
+    rng = np.random.default_rng(5)
+    L, N, ps, d = 3, 5, 4, 8
+    shape = (L, N, heads, ps, d) if heads else (L, N, ps, d)
+    pool = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    row = jnp.array([4, 0, 2, 1], jnp.int32)
+    lane = jnp.array([3, 0, 1, ps - 1], jnp.int32)
+    ok = jnp.array([True, True, False, True])
+    new = jnp.asarray(rng.normal(size=(4, heads, d) if heads else (4, d)), jnp.float32)
+    heads_ix = (slice(None),) if heads else ()
+    for layer in range(L):
+        got = page_write(pool, jnp.int32(layer), row, lane, ok, new)
+        want = pool
+        for b in np.flatnonzero(np.asarray(ok)):
+            want = want.at[(layer, int(row[b])) + heads_ix + (int(lane[b]),)].set(new[b])
+        assert jnp.array_equal(got, want), layer
+
+
 # ---------------------------------------------------------------------------
 # engine-level bitwise parity: paged vs dense through the real LM stack
 # ---------------------------------------------------------------------------
@@ -380,6 +440,49 @@ def test_paged_dmr_strike_detected_attributed_repaired():
     assert res["faults"] == 1
     assert eng.ledger.totals[req.id]["events"] == 1.0
     assert eng.ledger.totals[req.id]["per_replica"][1] == 1.0
+    # a replica resident: every step kept its input for the §IV replay
+    assert eng.metrics()["pool_in_place_ticks"] == 0
+
+
+def test_unreplicated_paged_step_writes_the_pool_in_place():
+    """No replicated request resident: the engine hands the decoder state
+    to the step, which writes the pool over its input.  The pre-step pool
+    buffer is deleted by every step, every tick's span says
+    ``pool_in_place`` 1, and the tokens still equal the dense twin's."""
+    from repro.obs import Tracer
+    from repro.serving.lm import lm_engine_parts
+
+    cfg, scfg = tiny_lm()
+    prompts = [np.arange(5 + i, dtype=np.int32) * (i + 2) % cfg.vocab_size for i in range(2)]
+
+    def run(eng):
+        reqs = [Request(prompt=p, max_new_tokens=6) for p in prompts]
+        assert eng.submit(reqs[0])
+        eng.pump(max_ticks=2)
+        assert eng.submit(reqs[1])  # joins mid-stream
+        eng.pump()
+        assert all(eng.result(r.id)["status"] == DONE for r in reqs)
+        return [eng.result(r.id)["tokens"] for r in reqs]
+
+    ref = run(lm_engine(cfg, scfg))
+    tr = Tracer()
+    prog, adapter = lm_engine_parts(cfg, paged_cfg(scfg))
+    eng = ServingEngine(prog, adapter, miso.EngineConfig(tracer=tr))
+    eng.start(jax.random.PRNGKey(0))
+    step, deleted = eng.exe.step, []
+
+    def spy(states, **kw):
+        pool = states[eng.adapter.cell]["cache"]["segments"][0]["k"]
+        out = step(states, **kw)
+        deleted.append(pool.is_deleted())
+        return out
+
+    eng.exe.step = spy
+    assert run(eng) == ref
+    assert deleted and all(deleted)
+    ticks = [e for e in tr.events() if e["ph"] == "X" and e["name"] == "tick"]
+    assert [e["args"]["pool_in_place"] for e in ticks] == [1] * len(deleted)
+    assert eng.metrics()["pool_in_place_ticks"] == len(deleted)
 
 
 @pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])

@@ -7,7 +7,9 @@ scratch that overruns VMEM — before any chip time is spent.  The topology
 is described inside a module fixture (never at import), so only the
 worker that runs this file loads the TPU compiler.
 """
+import dataclasses as dc
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -125,3 +127,51 @@ def test_ssd_scan_mamba2_widths(one_chip):
 def test_flash_attention_internlm2_prefill(one_chip):
     x = _spec(one_chip, (1, 16, MAX_LEN, 128), jnp.bfloat16)
     _compile(functools.partial(flash_attention, interpret=False), x, x, x)
+
+
+#: an instruction that writes a whole KV pool (one layer's, or a stack)
+#: by copying or slicing it: ``%name = bf16[...1536,8,16,128]{...} op(``
+POOL_COPY = re.compile(
+    r"%(\S*(?:copy|dynamic.slice|dynamic.update.slice)\S*) = "
+    r"bf16\[(?:\d+,)?1536,8,16,128\]\S* (\S+)\("
+)
+
+
+def test_paged_decode_step_updates_the_donated_pool_in_place(one_chip, monkeypatch):
+    """The serving step at the chat cell's pool (16 slots, 1536 pages of
+    16, internlm2 widths, 2 layers), its decoder cell donated: the pool
+    inputs are aliased to the outputs, and no instruction copies or slices
+    a pool, stacked or per layer."""
+    from repro.core.executor import compile as compile_program
+    from repro.core.fault import FaultSpec
+    from repro.core.jit import forwarding_jit
+    from repro.kernels import ops
+    from repro.models.lm_cells import ServeConfig
+    from repro.serving.lm import lm_engine_parts
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)  # compiled kernels
+    cfg = dc.replace(get_config("internlm2-1.8b"), n_layers=2)
+    scfg = ServeConfig(batch=16, max_len=4096, paged=True, page_size=16, page_budget=1536)
+    prog, _ = lm_engine_parts(cfg, scfg)
+    exe = compile_program(prog, backend="lockstep")
+    states = jax.tree.map(
+        lambda s: _spec(one_chip, s.shape, s.dtype),
+        jax.eval_shape(prog.init_states, jax.random.PRNGKey(0)),
+    )
+    pool = states["decoder"]["cache"]["segments"][0]
+    assert pool["k"].shape == (2, 1536, cfg.n_kv_heads, 16, cfg.d_model // cfg.n_heads)
+    args = (states, _spec(one_chip, (), jnp.int32), FaultSpec.none())
+    donate = ({"decoder": True, "weights": False}, False, False)
+    text = (
+        forwarding_jit(exe.step_fn, name="lockstep_step")
+        .lower(*args, donate=donate)
+        .compile()
+        .as_text()
+    )
+    leaves = jax.tree.leaves(args)
+    at = [i for i, x in enumerate(leaves) if x is pool["k"] or x is pool["v"]]
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    given = {int(i) for i in re.findall(r"\((\d+), \{\}, may-alias\)", aliased)}
+    assert len(at) == 2 and set(at) <= given, (at, aliased)
+    assert "paged_gqa_attention" in text
+    assert not POOL_COPY.findall(text), POOL_COPY.findall(text)
